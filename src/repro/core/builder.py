@@ -70,6 +70,7 @@ import numpy as np
 from repro.core import lsh as lsh_lib
 from repro.core.spanner import Graph
 from repro.core.stars import StarsConfig, _prefilter_sketch, _rep_candidates
+from repro.distributed.mesh import auto_axes
 from repro.graph import accumulator as acc_lib
 from repro.similarity import pair_cache as pc_lib
 from repro.similarity.measure import Measure, make_measure
@@ -146,15 +147,21 @@ class RepetitionSource:
             _prefilter_sketch(features, cfg.hamming_prefilter_bits, cfg.seed)
             if cfg.hamming_prefilter_bits > 0 else None)
 
+        # the feature tables ride as ARGUMENTS: a closed-over device array
+        # becomes a constant baked into the executable (n * d floats — a
+        # 1.2 GB TPU executable at n = 2^20, d = 100, slow to compile and
+        # too large for the persistent compilation cache)
+        tables = (features, prefilter, measure_state)
+
         if cache_slots <= 0:
             @functools.partial(jax.jit, donate_argnums=0)
-            def round_step(state, rep_index, probs):
-                out = _rep_candidates(cfg, features, measure, prefilter,
+            def round_step(state, rep_index, probs, tables):
+                feats, pref, mstate = tables
+                out = _rep_candidates(cfg, feats, measure, pref,
                                       rep_index, new_from=new_from,
                                       refresh_below=refresh_below,
                                       refresh_fraction=refresh_fraction,
-                                      refresh_probs=probs,
-                                      state=measure_state)
+                                      refresh_probs=probs, state=mstate)
                 state = acc_lib.accumulate(state, out["src"], out["dst"],
                                            out["w"], out["emit"])
                 return state, {k: out[k] for k in
@@ -162,17 +169,18 @@ class RepetitionSource:
                                 "scored_windows")}
 
             return lambda state, rep, probs=None: round_step(
-                state, jnp.int32(rep), probs)
+                state, jnp.int32(rep), probs, tables)
 
         r1 = cfg.r1
 
         @functools.partial(jax.jit, donate_argnums=(0, 3))
-        def round_step_cached(state, rep_index, probs, cache):
-            out = _rep_candidates(cfg, features, measure, prefilter,
+        def round_step_cached(state, rep_index, probs, cache, tables):
+            feats, pref, mstate = tables
+            out = _rep_candidates(cfg, feats, measure, pref,
                                   rep_index, new_from=new_from,
                                   refresh_below=refresh_below,
                                   refresh_fraction=refresh_fraction,
-                                  refresh_probs=probs, state=measure_state)
+                                  refresh_probs=probs, state=mstate)
             w, cache, hits, misses, evictions = pc_lib.lookup_insert(
                 cache, out["src"], out["dst"], out["w"], out["cmp"])
             # hits return the bit-identical score the tile recomputed (see
@@ -192,7 +200,7 @@ class RepetitionSource:
             return state, counters, cache
 
         return lambda state, rep, probs=None, cache=None: round_step_cached(
-            state, jnp.int32(rep), probs, cache)
+            state, jnp.int32(rep), probs, cache, tables)
 
 
 class AllPairsSource:
@@ -232,7 +240,8 @@ class AllPairsSource:
         r1 = cfg.r1
 
         @functools.partial(jax.jit, donate_argnums=0)
-        def block_step(state, a0, b0):
+        def block_step(state, a0, b0, features, measure_state):
+            # tables as arguments, not constants (RepetitionSource.bind)
             ids_a = a0 + jnp.arange(block, dtype=jnp.int32)
             ids_b = b0 + jnp.arange(block, dtype=jnp.int32)
             clamp_a = jnp.minimum(ids_a, n - 1)
@@ -259,7 +268,8 @@ class AllPairsSource:
                 for b0 in range(a0, n, block):
                     if new_from > 0 and b0 + block <= new_from:
                         continue                     # both endpoints old
-                    state = block_step(state, jnp.int32(a0), jnp.int32(b0))
+                    state = block_step(state, jnp.int32(a0), jnp.int32(b0),
+                                       features, measure_state)
             comps = n * (n - 1) // 2 - new_from * (new_from - 1) // 2
             return state, {"comparisons": comps}
 
@@ -383,7 +393,7 @@ class _SingleDeviceBackend:
         (repro.distributed.cluster_dist runs one code path at every p;
         p=1 parity with p=2/4 is proven in tests/test_cluster.py)."""
         if not hasattr(self, "_cluster_mesh"):
-            self._cluster_mesh = jax.make_mesh((1,), ("data",))
+            self._cluster_mesh = auto_axes(jax.make_mesh((1,), ("data",)))
         return self._cluster_mesh, "data"
 
 
@@ -592,7 +602,7 @@ class _PagedBackend:
 
     def cluster_mesh(self):
         if not hasattr(self, "_cluster_mesh"):
-            self._cluster_mesh = jax.make_mesh((1,), ("data",))
+            self._cluster_mesh = auto_axes(jax.make_mesh((1,), ("data",)))
         return self._cluster_mesh, "data"
 
     def ensure_measure_state(self) -> int:
@@ -853,7 +863,7 @@ class _MeshBackend:
                 "mesh backend scores cosine/dot or a state-complete "
                 "learned measure (the tera-scale settings)")
         self.cfg = cfg
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.axis = "data"
         self.p = mesh.shape[self.axis]
         self.measure = (measure if measure is not None else
@@ -1114,7 +1124,6 @@ class _MeshBackend:
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.core import windows as win_lib
         from repro.core.stars import _rep_keys, _score_windows
         cfg = self.cfg
@@ -1167,7 +1176,7 @@ class _MeshBackend:
                     out["comparisons"], out["emitted"],
                     out["prefilter_ops"], out["scored_windows"][None])
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             score_shard, mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis, None), P(axis), P())
             + ((P(),) if has_probs else ()),
@@ -1429,8 +1438,11 @@ class GraphBuilder:
       features: PointFeatures (or a bare (n, d) dense array).
       cfg:      StarsConfig; ``cfg.source_name`` selects the candidate
                 source, ``cfg.degree_cap`` sizes the slabs.
-      mesh:     optional jax Mesh — shards features and slabs over 'data'
-                (the former build_graph_distributed backend).
+      mesh:     optional jax Mesh with a 'data' axis — shards features
+                and slabs over it.  Any axis types are accepted (including
+                the ``Explicit`` axes ``jax.make_mesh`` defaults to): the
+                build runs on an ``Auto``-typed view of the same devices
+                (``repro.distributed.mesh.auto_axes``).
       measure:  for ``cfg.measure='learned'``: a
                 :class:`repro.similarity.measure.LearnedMeasure` (two-phase
                 embed/score — enables the embedding cache, the mesh wire

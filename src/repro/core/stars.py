@@ -451,6 +451,13 @@ def _rep_window_grid(cfg: StarsConfig, words: jax.Array,
         return win_lib.lsh_windows(bucket, window=cfg.window,
                                    tiebreak=tiebreak)
     if cfg.mode == "sorting":
+        if cfg.family.kind in ("simhash", "mixture"):
+            # one-bit words: pack them MSB-first (the mesh key layout,
+            # builder._sketch_keys) so the sort compares ceil(M/32) words
+            # instead of M — the same lexicographic order
+            from repro.distributed.sorter import pack_bit_fields
+            m = words.shape[1]
+            words = pack_bit_fields([words[:, j] for j in range(m)], [1] * m)
         return win_lib.sorting_lsh_windows(
             words, window=cfg.window, shift_key=k_shift, tiebreak=tiebreak)
     raise ValueError(f"unknown mode {cfg.mode!r}")

@@ -183,7 +183,9 @@ def lsh_windows(bucket_id: jax.Array, *, window: int,
     """
     n = bucket_id.shape[0]
     gids = jnp.arange(n, dtype=jnp.int32)
-    _, _, perm_gid = jax.lax.sort((bucket_id, tiebreak, gids), num_keys=2)
+    # gid is the last key: residual (bucket, tiebreak) ties resolve by gid,
+    # as in the mesh's packed keys, whatever the backend's sort stability
+    _, _, perm_gid = jax.lax.sort((bucket_id, tiebreak, gids), num_keys=3)
     perm_bucket = bucket_id[perm_gid]
     offset, n_slots = window_layout("lsh", n, window)
     return _scatter_to_slots(perm_gid, perm_bucket, offset, n_slots, window)
@@ -195,7 +197,8 @@ def sorting_lsh_windows(words: jax.Array, *, window: int,
     """Stars 2 windowing: exact lexicographic sort + random-shift blocks.
 
     Args:
-      words:     (n, M) uint32 hash words (h_1..h_M per point).
+      words:     (n, M) uint32 hash words (h_1..h_M per point), compared
+                 lexicographically (word 0 first).
       window:    W (paper: W = 16k for k-ANN; W = 250 in experiments).
       shift_key: PRNG key for the random shift r ~ [W/2, W].
       tiebreak:  (n,) uint32 random priorities for tie-breaking equal keys.
@@ -203,7 +206,7 @@ def sorting_lsh_windows(words: jax.Array, *, window: int,
     n, m = words.shape
     gids = jnp.arange(n, dtype=jnp.int32)
     operands = tuple(words[:, i] for i in range(m)) + (tiebreak, gids)
-    out = jax.lax.sort(operands, num_keys=m + 1)
+    out = jax.lax.sort(operands, num_keys=m + 2)    # gid resolves ties
     perm_gid = out[-1]
     # Random first-block size r in [W/2, W] -> slot offset (W - r) in [0, W/2].
     offset, n_slots = window_layout("sorting", n, window, shift_key)

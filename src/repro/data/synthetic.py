@@ -22,6 +22,7 @@ Everything is jit-friendly and reproducible from integer seeds.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -35,12 +36,18 @@ def gaussian_mixture_points(n: int, *, d: int = 100, modes: int = 100,
                             std: float = 0.1, seed: int = 0
                             ) -> Tuple[PointFeatures, np.ndarray]:
     """Appendix D.1 Random1B/10B generator (scaled to n points)."""
+    x, mode = _gaussian_mixture(n, d, modes, std, seed)
+    return PointFeatures(dense=x), np.asarray(mode)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _gaussian_mixture(n: int, d: int, modes: int, std: float, seed):
+    # one program on the device (op-by-op dispatch compiles each op apart)
     key = jax.random.key(seed)
     km, kx = jax.random.split(key)
     mode = jax.random.randint(km, (n,), 0, modes)
     x = jax.random.normal(kx, (n, d)) * std
-    x = x.at[jnp.arange(n), mode % d].add(1.0)
-    return PointFeatures(dense=x), np.asarray(mode)
+    return x.at[jnp.arange(n), mode % d].add(1.0), mode
 
 
 def mnist_like_points(n: int = 20_000, *, d: int = 64, classes: int = 10,

@@ -66,8 +66,9 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import all_to_all
 
-from repro.compat import all_to_all, shard_map
 from repro.distributed.sorter import exchange_capacity
 from repro.graph import accumulator as acc_lib
 

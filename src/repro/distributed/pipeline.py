@@ -24,9 +24,11 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pcast, shard_map
+from repro.distributed.mesh import auto_axes
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
@@ -88,7 +90,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
         return buf.reshape(xs.shape)[None]
 
     out = shard_map(
-        per_stage, mesh=mesh,
+        per_stage, mesh=auto_axes(mesh),
         in_specs=(P(axis), P()),
         out_specs=P(axis),
     )(stage_params, x)

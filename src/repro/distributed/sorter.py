@@ -13,7 +13,7 @@ axis:
      degradation as the paper's bucket-size caps; drops are zero for
      near-uniform hash keys unless cap is set adversarially small),
   4. ONE all_to_all exchanges the stacked (keys..., payload) buffer
-     (``repro.compat.all_to_all``; bytes recorded in
+     (``jax.lax.all_to_all``; bytes recorded in
      ``accumulator.transfer_stats['all_to_all_bytes']``),
   5. local merge-sort of the received keys (invalid slots carry all-ones
      sentinel keys and sort to the tail).
@@ -55,8 +55,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import all_to_all, axis_size, psum_scatter, shard_map
+from jax import shard_map
+from jax.lax import all_to_all, axis_size, psum_scatter
 
 SENTINEL = jnp.uint32(0xFFFFFFFF)
 

@@ -56,8 +56,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import all_to_all
 
-from repro.compat import all_to_all, shard_map
 from repro.core.spanner import Graph
 from repro.core.stars import StarsConfig
 from repro.distributed.sorter import (exchange_capacity, pack_bit_fields,

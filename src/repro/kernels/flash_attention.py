@@ -24,11 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it to
-# CompilerParams.  Resolve whichever exists so both sides of the rename work.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or getattr(
-    pltpu, "CompilerParams")
-
 _NEG_INF = float("-inf")
 
 
@@ -131,7 +126,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # running normalizer
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

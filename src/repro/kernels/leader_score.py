@@ -13,9 +13,11 @@ written as -inf so the consumer can threshold/top-k without re-reading
 features.  HBM traffic drops to one read of each feature tile plus the
 (s x W) similarity write.
 
-Block shape: (block_w windows, s, d) x (block_w, W, d) per step; s and W are
-already hardware-friendly (s <= 32 pads to 128 on the MXU's minor dim; the
-W = 250-ish window pads to 256).
+Blocking and numerics are ``window_score``'s (kernels/window_score.py):
+``BLOCK_WINDOWS`` windows per grid step over (BLOCK_WINDOWS, s) /
+(BLOCK_WINDOWS, W) flag tiles, normalization by division by sqrt and a
+HIGHEST-precision contraction, so on TPU the similarities keep float32
+accuracy instead of the MXU's default bfloat16 operand rounding.
 """
 
 from __future__ import annotations
@@ -25,20 +27,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.window_score import (BLOCK_WINDOWS, _similarity,
+                                        out_struct)
 
 
 def _leader_score_kernel(l_ref, m_ref, lok_ref, mok_ref, out_ref, *,
                          normalized: bool):
-    lead = l_ref[0].astype(jnp.float32)          # (s, d)
-    memb = m_ref[0].astype(jnp.float32)          # (w, d)
-    if normalized:
-        ln = jax.lax.rsqrt(jnp.sum(lead * lead, -1, keepdims=True) + 1e-12)
-        mn = jax.lax.rsqrt(jnp.sum(memb * memb, -1, keepdims=True) + 1e-12)
-        lead = lead * ln
-        memb = memb * mn
-    sims = jnp.dot(lead, memb.T, preferred_element_type=jnp.float32)
-    mask = lok_ref[0][:, None] & mok_ref[0][None, :]
-    out_ref[0] = jnp.where(mask, sims, -jnp.inf).astype(jnp.float32)
+    sims = _similarity(l_ref[...], m_ref[...], normalized)  # (B, s, w)
+    # Mosaic cannot shape-cast i1 vectors: widen flags before broadcasting
+    lok = lok_ref[...].astype(jnp.int32)[:, :, None] != 0
+    mok = mok_ref[...].astype(jnp.int32)[:, None, :] != 0
+    out_ref[...] = jnp.where(lok & mok, sims, -jnp.inf)
 
 
 def leader_score(leaders: jax.Array, members: jax.Array,
@@ -52,17 +53,17 @@ def leader_score(leaders: jax.Array, members: jax.Array,
     """
     nw, s, d = leaders.shape
     _, w, _ = members.shape
-    grid = (nw,)
+    bw = BLOCK_WINDOWS
+    rows = lambda *tail: pl.BlockSpec((bw,) + tail,
+                                      lambda i: (i,) + (0,) * len(tail))
     return pl.pallas_call(
         functools.partial(_leader_score_kernel, normalized=normalized),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, w, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-            pl.BlockSpec((1, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, s, w), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nw, s, w), jnp.float32),
+        grid=(pl.cdiv(nw, bw),),
+        in_specs=[rows(s, d), rows(w, d), rows(s), rows(w)],
+        out_specs=rows(s, w),
+        out_shape=out_struct((nw, s, w), jnp.float32, leaders, members,
+                             leader_ok, member_ok),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(leaders, members, leader_ok, member_ok)

@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU backends the Pallas kernels lower natively; on CPU
-(this container) they run under ``interpret=True`` for correctness tests,
-while the *default* CPU path uses the pure-jnp reference so large CPU jobs
-(benchmarks, smoke tests) stay fast.  ``use_pallas`` overrides the choice.
+Dispatch policy: on TPU backends the Pallas kernels lower natively and are
+the default; on CPU the default is the pure-jnp reference (``ref.py``), and
+``use_pallas=True`` runs a kernel under ``interpret=True`` (the kernel
+tests).  ``use_pallas`` overrides the choice; on TPU both choices run
+natively, which is how the chip compares each kernel with its oracle
+(``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -79,8 +81,8 @@ def window_score(leaders, members, leader_slot, lead_gid, gid, leader_ok,
     The whole per-window pipeline of ``core/stars._score_windows`` in one
     op — see ``ref.window_score_ref`` for the shape/mask contract.  The
     Pallas kernel (``kernels/window_score.py``) shares the reference's
-    exact normalization and contraction, so both paths are bit-identical
-    and the mesh edge-for-edge parity is dispatch-independent.
+    normalization and HIGHEST-precision contraction: discrete outputs are
+    equal and similarities agree to ~1 ulp.
     """
     use, interp = _pick(use_pallas)
     if use:

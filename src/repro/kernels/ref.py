@@ -32,6 +32,8 @@ def leader_score_ref(leaders: jax.Array, members: jax.Array,
     leaders: (nw, s, d); members: (nw, w, d); masks (nw, s) / (nw, w).
     Returns (nw, s, w) float32; masked entries are -inf.
     Cosine when normalized=True (inputs l2-normalized inside), else dot.
+    The contraction runs at HIGHEST precision: the TPU default would round
+    float32 operands to bfloat16 (see kernels/window_score.py).
     """
     if normalized:
         nrm = lambda t: t / jnp.sqrt(
@@ -39,7 +41,8 @@ def leader_score_ref(leaders: jax.Array, members: jax.Array,
         la, mb = nrm(leaders), nrm(members)
     else:
         la, mb = leaders.astype(jnp.float32), members.astype(jnp.float32)
-    sims = jnp.einsum("nsd,nwd->nsw", la, mb)
+    sims = jnp.einsum("nsd,nwd->nsw", la, mb,
+                      precision=jax.lax.Precision.HIGHEST)
     mask = leader_ok[:, :, None] & member_ok[:, None, :]
     return jnp.where(mask, sims, -jnp.inf).astype(jnp.float32)
 

@@ -15,18 +15,25 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+# Auto axes: the parameter / batch specs and activation constraints
+# (launch/sharding.py, distributed/activation_sharding.py) are bare
+# PartitionSpecs resolved against the context mesh.
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // n_model, n_model), ("data", "model"))
+    return jax.make_mesh((n // n_model, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh: jax.sharding.Mesh):

@@ -107,49 +107,52 @@ def two_hop_neighbors(nbr: jax.Array, w: jax.Array, q: jax.Array, *,
     each query (forward row scatter + reverse ``nbr == q`` scan), then the
     second hop through every one-hop member u (forward row[u] scatter +
     reverse containment gather), keeping the best bottleneck weight
-    ``min(w(q,u), w(u,v))`` per member.  O(m * n * k) compute, O(m * q_cap)
-    output — nothing O(n * k) ever leaves the device.
+    ``min(w(q,u), w(u,v))`` per member.  Queries run one after another
+    (``lax.map``), so the working set is O(n * k) whatever the batch size —
+    a single query's intermediates already take ~3 GB at n = 2^20, k = 250.
+    O(m * n * k) compute, O(m * q_cap) output — nothing O(n * k) ever
+    leaves the device.
 
     Returns (ids (m, q_cap) int32 with -1 fill, weights (m, q_cap),
     member_count (m,) int32, truncated scalar int32).
     """
+    ids, top_w, count = jax.lax.map(
+        lambda qi: _two_hop_one(nbr, w, qi, q_cap), q)
+    truncated = jnp.sum(count > q_cap).astype(jnp.int32)
+    return ids, top_w, count, truncated
+
+
+def _two_hop_one(nbr: jax.Array, w: jax.Array, q: jax.Array, q_cap: int):
+    """One query of :func:`two_hop_neighbors`: (ids, weights, count)."""
     n, k = nbr.shape
-    m = q.shape[0]
     qc = jnp.clip(q, 0, n - 1)
     valid_q = (q >= 0) & (q < n)
     neg_inf = jnp.float32(-jnp.inf)
 
-    # symmetric one-hop weights (m, n): forward rows scatter into a grid
-    # with a dump column at n; reverse scan catches edges recorded only in
-    # the OTHER endpoint's row (the from_degree_slabs union semantics)
-    row_n, row_w = nbr[qc], w[qc]                       # (m, k)
+    # symmetric one-hop weights (n,): the forward row scatters into a grid
+    # with a dump slot at n; the reverse scan catches edges recorded only
+    # in the OTHER endpoint's row (the from_degree_slabs union semantics)
+    row_n, row_w = nbr[qc], w[qc]                       # (k,)
     tgt = jnp.where(row_n >= 0, row_n, n)
-    i_idx = jnp.broadcast_to(jnp.arange(m)[:, None], (m, k))
-    grid = jnp.full((m, n + 1), neg_inf).at[i_idx, tgt].max(row_w)[:, :n]
-    rev = jnp.where(nbr[None, :, :] == qc[:, None, None],
-                    w[None, :, :], neg_inf).max(axis=2)  # (m, n)
-    one_w = jnp.maximum(grid, rev)
-    one_w = jnp.where(valid_q[:, None], one_w, neg_inf)
+    grid = jnp.full((n + 1,), neg_inf).at[tgt].max(row_w)[:n]
+    rev = jnp.where(nbr == qc, w, neg_inf).max(axis=1)  # (n,)
+    one_w = jnp.where(valid_q, jnp.maximum(grid, rev), neg_inf)
 
     # second hop through every one-hop u: forward = row[u] entries,
     # reverse = rows v whose slab contains u; bottleneck-weight scoring
-    fw = jnp.minimum(one_w[:, :, None], w[None, :, :])   # (m, n, k)
-    tgt2 = jnp.broadcast_to(jnp.where(nbr >= 0, nbr, n)[None], (m, n, k))
-    i2 = jnp.broadcast_to(jnp.arange(m)[:, None, None], (m, n, k))
-    two_f = jnp.full((m, n + 1), neg_inf).at[i2, tgt2].max(fw)[:, :n]
-    uidx = jnp.where(nbr >= 0, nbr, n)                   # (n, k)
-    one_pad = jnp.concatenate([one_w, jnp.full((m, 1), neg_inf)], axis=1)
-    two_r = jnp.minimum(one_pad[:, uidx], w[None, :, :]).max(axis=2)
+    fw = jnp.minimum(one_w[:, None], w)                 # (n, k)
+    uidx = jnp.where(nbr >= 0, nbr, n)                  # (n, k)
+    two_f = jnp.full((n + 1,), neg_inf).at[uidx].max(fw)[:n]
+    one_pad = jnp.concatenate([one_w, jnp.full((1,), neg_inf)])
+    two_r = jnp.minimum(one_pad[uidx], w).max(axis=1)
     two_w = jnp.maximum(two_f, two_r)
 
     score = jnp.maximum(one_w, two_w)
-    score = jnp.where(jnp.arange(n)[None, :] != qc[:, None], score, neg_inf)
-    member = score > neg_inf
-    count = member.sum(axis=1).astype(jnp.int32)
+    score = jnp.where(jnp.arange(n) != qc, score, neg_inf)
+    count = (score > neg_inf).sum().astype(jnp.int32)
     top_w, top_i = jax.lax.top_k(score, q_cap)
     ids = jnp.where(top_w > neg_inf, top_i.astype(jnp.int32), -1)
-    truncated = jnp.sum(count > q_cap).astype(jnp.int32)
-    return ids, top_w, count, truncated
+    return ids, top_w, count
 
 
 class ServeSession:

@@ -300,7 +300,7 @@ def test_topk_merge_sorted_ref_all_sentinel_rows():
 
 @pytest.mark.fast
 @pytest.mark.parametrize("n,k,kin", [(1, 4, 4), (17, 8, 8), (64, 16, 8),
-                                     (5, 3, 9)])
+                                     (5, 3, 9), (11, 250, 250)])
 def test_topk_merge_kernel_matches_ref(n, k, kin):
     rs = np.random.RandomState(n * k + kin)
     def slabs(cols):

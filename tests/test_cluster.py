@@ -206,6 +206,16 @@ _COMMON = """
 """
 
 
+# Independent hash / leader draws of the same corpus.  Affinity's Boruvka
+# rounds merge every cluster at once and stop wherever a round lands at or
+# below the target, so one draw's mesh-vs-host v-measure swings widely
+# (seeds 0-11 at p=1: 0.35-0.975, mean 0.75; the mesh recomputes true
+# average linkage, the host averages contracted means).  The parity claim
+# is about the expected labels, so it is asserted on the mean over draws;
+# the zero-gather accounting and exact components hold for every draw.
+AFFINITY_SEEDS = range(6)
+
+
 @pytest.mark.dist
 @pytest.mark.parametrize("devices", [1, 2, 4])
 def test_mesh_cluster_zero_gather_parity(devices):
@@ -218,57 +228,62 @@ def test_mesh_cluster_zero_gather_parity(devices):
     res = _run_sub(_COMMON + f"""
         feats, y = mnist_like_points(n=402, d=24, classes=6, spread=0.12,
                                      seed=0)
-        cfg = StarsConfig(mode="sorting", scoring="stars",
-                          family=HashFamilyConfig("simhash", m=16),
-                          measure="cosine", r=6, window=64, leaders=8,
-                          degree_cap=16, seed=7)
         mesh = jax.make_mesh(({devices},), ("data",))
-        b = GraphBuilder(feats.dense, cfg, mesh=mesh)
-        b.add_reps(6)
-        acc_lib.reset_transfer_stats()
-        lab_cc, info_cc = b.cluster("components", return_info=True)
-        lab_af, info_af = b.cluster("affinity", target_clusters=6,
-                                    return_info=True)
-        ts = dict(acc_lib.transfer_stats)
-        g = b.finalize()                       # the ONE edge fetch, AFTER
-        host_cc = connected_components_np(g.n, g.src, g.dst)
-        host_af = affinity_clustering(g, target_clusters=6)
-        out = {{
-            "edge_fetches_before_labels": ts["edge_fetches"],
-            "edge_bytes_before_labels": ts["bytes"],
-            "a2a_bytes": ts["all_to_all_bytes"],
-            "a2a_calls": ts["all_to_all_calls"],
-            "label_fetches": ts["cluster_label_fetches"],
-            "label_bytes": ts["cluster_label_bytes"],
-            "cc_exact": bool(np.array_equal(lab_cc, host_cc)),
-            "cc_rounds": info_cc["rounds"],
-            "cc_converged": info_cc["converged"],
-            "af_rounds": info_af["rounds"],
-            "v_mesh_vs_host": v_measure(host_af, lab_af)["v"],
-            "v_host_truth": v_measure(y, host_af)["v"],
-            "v_mesh_truth": v_measure(y, lab_af)["v"],
-        }}
-        print(json.dumps(out))
-    """, devices)
-    # ZERO global edge fetches before cluster labels — the tentpole
-    assert res["edge_fetches_before_labels"] == 0
-    assert res["edge_bytes_before_labels"] == 0
-    # the only device->host payload: two (n,) int32 label vectors
-    assert res["label_fetches"] == 2
-    assert res["label_bytes"] == 2 * 402 * 4
-    # label rounds ride the metered exchange idiom: cross-shard bytes are
-    # exactly 0 on one shard and real traffic beyond
-    if devices == 1:
-        assert res["a2a_bytes"] == 0
-    else:
-        assert res["a2a_bytes"] > 0
-    assert res["a2a_calls"] > 0
-    assert res["cc_exact"], res
-    assert res["cc_converged"]
+        draws = []
+        for seed in {list(AFFINITY_SEEDS)}:
+            cfg = StarsConfig(mode="sorting", scoring="stars",
+                              family=HashFamilyConfig("simhash", m=16),
+                              measure="cosine", r=6, window=64, leaders=8,
+                              degree_cap=16, seed=seed)
+            b = GraphBuilder(feats.dense, cfg, mesh=mesh)
+            b.add_reps(6)
+            acc_lib.reset_transfer_stats()
+            lab_cc, info_cc = b.cluster("components", return_info=True)
+            lab_af, info_af = b.cluster("affinity", target_clusters=6,
+                                        return_info=True)
+            ts = dict(acc_lib.transfer_stats)
+            g = b.finalize()                   # the ONE edge fetch, AFTER
+            host_cc = connected_components_np(g.n, g.src, g.dst)
+            host_af = affinity_clustering(g, target_clusters=6)
+            draws.append({{
+                "edge_fetches_before_labels": ts["edge_fetches"],
+                "edge_bytes_before_labels": ts["bytes"],
+                "a2a_bytes": ts["all_to_all_bytes"],
+                "a2a_calls": ts["all_to_all_calls"],
+                "label_fetches": ts["cluster_label_fetches"],
+                "label_bytes": ts["cluster_label_bytes"],
+                "cc_exact": bool(np.array_equal(lab_cc, host_cc)),
+                "cc_rounds": info_cc["rounds"],
+                "cc_converged": info_cc["converged"],
+                "af_rounds": info_af["rounds"],
+                "v_mesh_vs_host": v_measure(host_af, lab_af)["v"],
+                "v_host_truth": v_measure(y, host_af)["v"],
+                "v_mesh_truth": v_measure(y, lab_af)["v"],
+            }})
+        print(json.dumps({{"draws": draws}}))
+    """, devices)["draws"]
+    for d in res:
+        # ZERO global edge fetches before cluster labels — the tentpole
+        assert d["edge_fetches_before_labels"] == 0
+        assert d["edge_bytes_before_labels"] == 0
+        # the only device->host payload: two (n,) int32 label vectors
+        assert d["label_fetches"] == 2
+        assert d["label_bytes"] == 2 * 402 * 4
+        # label rounds ride the metered exchange idiom: cross-shard bytes
+        # are exactly 0 on one shard and real traffic beyond
+        if devices == 1:
+            assert d["a2a_bytes"] == 0
+        else:
+            assert d["a2a_bytes"] > 0
+        assert d["a2a_calls"] > 0
+        assert d["cc_exact"], d
+        assert d["cc_converged"]
     # v-measure parity with the host path (merge orders may differ; the
     # mesh recomputes true average linkage each round — see cluster_dist)
-    assert res["v_mesh_vs_host"] >= 0.6, res
-    assert res["v_mesh_truth"] >= res["v_host_truth"] - 0.15, res
+    mean = lambda key: float(np.mean([d[key] for d in res]))
+    assert len(res) == len(AFFINITY_SEEDS)
+    assert mean("v_mesh_vs_host") >= 0.6, res
+    assert mean("v_mesh_truth") >= mean("v_host_truth") - 0.15, res
 
 
 @pytest.mark.dist

@@ -112,6 +112,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.models import ModelConfig, init_params
         from repro.train import AdamWConfig, TrainState, make_train_step
         from repro.launch.sharding import plan_param_specs, batch_specs, named
+        from repro.launch.mesh import make_host_mesh
         from repro.launch.specs import abstract_params
         from repro.data import token_stream_batch
         from repro.distributed import activation_sharding
@@ -128,7 +129,7 @@ def test_sharded_train_step_matches_single_device():
         step = make_train_step(cfg, opt)
         s_ref, m_ref = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(n_model=2)            # (4, 2) data x model
         shapes, _ = abstract_params(cfg)
         pspecs = plan_param_specs(cfg, axes, mesh, shapes)
         p_sh = named(mesh, pspecs)

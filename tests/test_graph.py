@@ -93,6 +93,40 @@ def test_single_linkage_sweep_theorem_a3():
     assert v_measure(truth, labels)["v"] == pytest.approx(1.0)
 
 
+def _csr_recall(g, queries, truths, hops, k_cap):
+    """neighbor_recall spelled out over the whole-graph CSR."""
+    indptr, nbrs, _ = g.to_csr()
+    adj = lambda v: nbrs[indptr[v]:indptr[v + 1]]
+    ratios = []
+    for q, truth in zip(queries, truths):
+        found = set(adj(q).tolist())
+        if hops == 2:
+            found |= {int(u) for z in adj(q) for u in adj(z)}
+        inter = len(found & set(truth.tolist()))
+        ratios.append(1.0 if k_cap is not None and inter >= k_cap
+                      else inter / truth.size)
+    return float(np.mean(ratios))
+
+
+@pytest.mark.parametrize("hops,k_cap", [(1, None), (2, None), (2, 5)])
+def test_neighbor_recall_on_graph_and_slabs_matches_csr(hops, k_cap):
+    from repro.graph import neighbor_recall, slab_neighbor_recall
+    rs = np.random.RandomState(hops * 10 + (k_cap or 0))
+    n, k = 80, 2
+    nbr = rs.randint(-1, n, (n, k))
+    nbr[nbr == np.arange(n)[:, None]] = -1          # slabs hold no self-loops
+    w = np.where(nbr >= 0, rs.rand(n, k), -np.inf).astype(np.float32)
+    g = Graph.from_degree_slabs(n, nbr, w)
+    queries = rs.choice(n, 12, replace=False)
+    truths = [rs.choice(n, 6, replace=False) for _ in queries]
+    want = _csr_recall(g, queries, truths, hops, k_cap)
+    assert 0.0 < want < 1.0
+    assert neighbor_recall(g, queries, truths, hops=hops,
+                           k_cap=k_cap) == pytest.approx(want)
+    assert slab_neighbor_recall(nbr, queries, truths, hops=hops,
+                                k_cap=k_cap) == pytest.approx(want)
+
+
 def test_two_hop_sets():
     # path graph 0-1-2-3
     g = Graph.from_candidates(4, np.array([0, 1, 2]), np.array([1, 2, 3]),

@@ -25,33 +25,44 @@ def dataset():
     return mnist_like_points(n=4000, d=32, classes=10, spread=0.15, seed=3)
 
 
-def _cfg(scoring, r=20, leaders=10, window=150):
+def _cfg(scoring, r=20, leaders=10, window=150, seed=7):
     # r=20 / W=150 / s=10 tuned against paper Fig. 4's "Stars matches
     # non-Stars quality" claim: at r=15/W=100 the affinity pipeline sat just
     # under the 0.8 VMeasure bar (0.784) while non-Stars scored 0.92+ — an
     # under-repetition artifact, not a Stars-vs-baseline gap.  At r=20/W=150
-    # both variants land at ~0.93 (sweep: PR 2) with a 7.4x comparison
+    # both variants land at ~0.86-0.88 on average with a 7.4x comparison
     # reduction, so the Fig. 1 ratio assertion keeps a wide margin too.
     return StarsConfig(mode="sorting", scoring=scoring,
                        family=HashFamilyConfig("simhash", m=20),
                        measure="cosine", r=r, window=window, leaders=leaders,
-                       degree_cap=50, seed=7)
+                       degree_cap=50, seed=seed)
+
+
+# Independent hash / leader draws of the same corpus.  One draw's VMeasure
+# swings by up to +-0.1 for BOTH variants, because Boruvka rounds merge
+# every cluster at once and stop wherever the round lands at or below the
+# target (9 seeds at r=20: Stars 0.76-0.92, non-Stars 0.81-0.97, mean gap
+# 0.02; r=30 and r=40 spread as widely).  The Fig. 4 claim is about the
+# expected quality, so it is asserted on the mean over draws.
+QUALITY_SEEDS = range(6)
 
 
 def test_stars_vs_nonstars_comparisons_and_quality(dataset):
     feats, labels = dataset
-    g_stars = build_graph(feats, _cfg("stars"))
-    g_all = build_graph(feats, _cfg("allpairs"))
-    # Fig 1: comparison reduction
-    ratio = g_all.stats["comparisons"] / g_stars.stats["comparisons"]
-    assert ratio > 3.0, ratio
+    v_stars, v_all = [], []
+    for seed in QUALITY_SEEDS:
+        g_stars = build_graph(feats, _cfg("stars", seed=seed))
+        g_all = build_graph(feats, _cfg("allpairs", seed=seed))
+        # Fig 1: comparison reduction
+        ratio = g_all.stats["comparisons"] / g_stars.stats["comparisons"]
+        assert ratio > 3.0, (seed, ratio)
+        v_stars.append(v_measure(labels, affinity_clustering(
+            g_stars.degree_cap(10), target_clusters=10))["v"])
+        v_all.append(v_measure(labels, affinity_clustering(
+            g_all.degree_cap(10), target_clusters=10))["v"])
     # Fig 4: no quality loss
-    v_stars = v_measure(labels, affinity_clustering(
-        g_stars.degree_cap(10), target_clusters=10))["v"]
-    v_all = v_measure(labels, affinity_clustering(
-        g_all.degree_cap(10), target_clusters=10))["v"]
-    assert v_stars > 0.8
-    assert v_stars > v_all - 0.05
+    assert np.mean(v_stars) > 0.8, v_stars
+    assert np.mean(v_stars) > np.mean(v_all) - 0.05, (v_stars, v_all)
 
 
 def test_end_to_end_learned_similarity_pipeline():
