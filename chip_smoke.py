@@ -23,7 +23,7 @@ s=25, degree cap k=250) through ``GraphBuilder``, then served through
            similarities and sketch bits
   recall   two-hop 10-NN recall of 1,024 sampled points against exact
            neighbours from a plain jnp matmul on the chip, at least
-           OVER_CHANCE times the recall of a random graph of equal degree
+           RECALL_FLOOR
 
 Four chips (``--chips 4``) runs only the mesh path: a p=4 mesh build over
 ``jax.devices()`` compared slab for slab with a single-device build on
@@ -60,7 +60,7 @@ EXT_REPS = 2                # repetitions per absorbed extension
 QUERIES = 1024              # recall sample
 NN = 10                     # recall's k
 SIM_TOL = 1e-5              # |kernel - oracle| on similarities (HIGHEST)
-OVER_CHANCE = 3.0           # recall floor, in multiples of chance recall
+RECALL_FLOOR = 0.13         # two-hop 10-NN recall at n = 2^20 (PERF.md)
 MESH_REPS = 2               # one coalesced repetition pair on the mesh
 
 
@@ -290,12 +290,17 @@ def _kernels_vs_oracle(clock: _Clock, builder, seed: int) -> None:
                    f"window_score {kw}: emit")
             _check(np.array_equal(np.asarray(got[2]), np.asarray(want[2])),
                    f"window_score {kw}: comparisons")
-            if "r1" not in kw:
-                _check(np.array_equal(np.asarray(got[3]),
-                                      np.asarray(want[3])),
-                       f"window_score {kw}: emitted")
-            else:
-                _log(f"  lanes within SIM_TOL of r1: {int((~clear).sum())}")
+            # per-window emitted counts: equal on every window without an
+            # exempt lane; elsewhere off by at most its exempt lanes
+            exempt = (~clear).sum(axis=(1, 2))
+            gap = np.abs(np.asarray(got[3]).astype(np.int64)
+                         - np.asarray(want[3]))
+            _check(not gap[exempt == 0].any() and (gap <= exempt).all(),
+                   f"window_score {kw}: emitted")
+            if "r1" in kw:
+                _log(f"  lanes within SIM_TOL of r1: {int(exempt.sum())} in "
+                     f"{int((exempt > 0).sum())} windows, emitted off by "
+                     f"{int(gap.sum())}")
             _log(f"kernel window_score {kw}: discrete outputs equal, "
                  f"max|dsim|={err:.3e}, comparisons="
                  f"{int(np.asarray(got[2]).sum())}, kernel_s={kernel_s:.4f}"
@@ -343,10 +348,10 @@ def _kernels_vs_oracle(clock: _Clock, builder, seed: int) -> None:
          f"sketch bits flipped={flipped:.3e}")
 
 
-def _recall(clock: _Clock, graph, x: jax.Array, seed: int) -> None:
-    """Two-hop 10-NN recall, held to OVER_CHANCE times the recall a graph of
-    the same mean degree reaches by chance (two-hop sets of ~degree^2
-    random points).  Recall itself grows with R; the smoke runs few."""
+def _recall(clock: _Clock, graph, x: jax.Array, seed: int) -> float:
+    """Two-hop 10-NN recall of QUERIES sampled points, beside the recall a
+    graph of the same mean degree reaches by chance (two-hop sets of
+    ~degree^2 random points).  Recall grows with R; the smoke runs few."""
     from repro.graph import neighbor_recall
     n = x.shape[0]
     queries = _sample(n, seed)
@@ -354,18 +359,17 @@ def _recall(clock: _Clock, graph, x: jax.Array, seed: int) -> None:
         truth = _exact_knn(x, queries, NN)
         recall = neighbor_recall(graph, queries, list(truth), hops=2)
     degree = 2 * graph.num_edges / n
-    chance = min(1.0, degree * degree / n)
     _log(f"recall: two_hop_{NN}nn={recall:.4f} queries={QUERIES} "
-         f"mean_degree={degree:.1f} chance={chance:.4f}")
-    _check(recall >= OVER_CHANCE * chance,
-           f"recall {recall} < {OVER_CHANCE} x chance {chance}")
+         f"mean_degree={degree:.1f} chance={min(1.0, degree**2 / n):.4f}")
+    return recall
 
 
 def one_chip(seed: int) -> None:
     clock = _Clock()
     builder, graph = _build_and_serve(clock, seed)
     _kernels_vs_oracle(clock, builder, seed)
-    _recall(clock, graph, builder.feature_store.features.dense, seed)
+    recall = _recall(clock, graph, builder.feature_store.features.dense, seed)
+    _check(recall >= RECALL_FLOOR, f"recall {recall} < {RECALL_FLOOR}")
     _log(f"peak_bytes_in_use={_peak_bytes(jax.devices()[0])} "
          f"compile_s_total={clock.compile_s:.3f}")
 
@@ -375,10 +379,27 @@ def one_chip(seed: int) -> None:
 # --------------------------------------------------------------------------- #
 
 
+def _slab_recall(nbr: np.ndarray, queries: np.ndarray, truth) -> float:
+    """Two-hop recall of the graph the (n, k) slab table ``nbr`` finalizes
+    to, without compacting all of it: the edges that touch a query or a
+    true neighbour are all that two-hop recall reads."""
+    from repro.core.spanner import Graph
+    from repro.graph import neighbor_recall
+    n, k = nbr.shape
+    member = np.zeros(n + 1, bool)       # member[-1]: empty entries (-1)
+    member[queries] = True
+    member[np.concatenate(truth)] = True
+    flat = nbr.ravel()
+    hit = np.flatnonzero((np.repeat(member[:n], k) | member[flat])
+                         & (flat >= 0))
+    ones = np.ones(hit.size)
+    sub = Graph.from_candidates(n, hit // k, flat[hit], ones, ones)
+    return neighbor_recall(sub, queries, truth, hops=2)
+
+
 def four_chips(seed: int) -> None:
     from repro.core import GraphBuilder
     from repro.graph import accumulator as acc_lib
-    from repro.graph import slab_neighbor_recall
 
     clock = _Clock()
     devices = jax.devices()[:4]
@@ -424,7 +445,7 @@ def four_chips(seed: int) -> None:
     queries = _sample(big_n, seed)
     with clock.phase("recall 4x"):
         truth = _exact_knn(x, queries, NN)
-        recall = slab_neighbor_recall(nbr, queries, list(truth), hops=2)
+        recall = _slab_recall(nbr, queries, list(truth))
     _log(f"mesh 4x: n={big_n} two_hop_{NN}nn={recall:.4f} "
          f"all_to_all_bytes={wire['all_to_all_bytes']} "
          f"all_to_all_calls={wire['all_to_all_calls']} "

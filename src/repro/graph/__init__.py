@@ -16,7 +16,6 @@ from repro.graph.affinity import affinity_clustering
 from repro.graph.single_linkage import single_linkage_from_spanners
 from repro.graph.metrics import (
     neighbor_recall,
-    slab_neighbor_recall,
     two_hop_threshold_recall,
     v_measure,
 )
@@ -33,7 +32,6 @@ __all__ = [
     "affinity_clustering",
     "single_linkage_from_spanners",
     "neighbor_recall",
-    "slab_neighbor_recall",
     "two_hop_threshold_recall",
     "v_measure",
 ]

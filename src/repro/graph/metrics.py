@@ -4,8 +4,7 @@
                                 and completeness (Fig. 4).
   * ``neighbor_recall``       — fraction of (approximate) k-nearest
                                 neighbours found in 1 or 2 hops (Fig. 2,
-                                SortingLSH variants); ``slab_neighbor_recall``
-                                is the same figure read off degree slabs.
+                                SortingLSH variants).
   * ``two_hop_threshold_recall`` — fraction of ground-truth pairs with
                                 similarity >= r reachable in <= 2 hops using
                                 edges of weight >= r1 (Fig. 2, LSH variants;
@@ -59,26 +58,8 @@ def _adjacency(graph: Graph, nodes: np.ndarray) -> dict:
     member = np.zeros(graph.n, bool)
     member[nodes] = True
     fwd, bwd = member[graph.src], member[graph.dst]
-    return _group(nodes, np.concatenate([graph.src[fwd], graph.dst[bwd]]),
-                  np.concatenate([graph.dst[fwd], graph.src[bwd]]))
-
-
-def _slab_adjacency(nbr: np.ndarray, nodes: np.ndarray) -> dict:
-    """``_adjacency`` of the graph ``Graph.from_degree_slabs`` would build
-    from the (n, k) slab table ``nbr`` (-1 = empty), read off the table
-    directly: a node's neighbours are its own row plus every row holding
-    it (slabs never hold self-loops)."""
-    n, k = nbr.shape
-    member = np.zeros(n + 1, bool)       # member[-1]: empty entries (-1)
-    member[nodes] = True
-    flat = nbr.ravel()
-    fwd = np.flatnonzero(np.repeat(member[:n], k) & (flat >= 0))
-    bwd = np.flatnonzero(member[flat])
-    return _group(nodes, np.concatenate([fwd // k, flat[bwd]]),
-                  np.concatenate([flat[fwd], bwd // k]))
-
-
-def _group(nodes: np.ndarray, ends: np.ndarray, nbrs: np.ndarray) -> dict:
+    ends = np.concatenate([graph.src[fwd], graph.dst[bwd]])
+    nbrs = np.concatenate([graph.dst[fwd], graph.src[bwd]])
     order = np.argsort(ends, kind="stable")
     ends, nbrs = ends[order], nbrs[order].astype(np.int64)
     lo = np.searchsorted(ends, nodes, side="left")
@@ -101,25 +82,10 @@ def neighbor_recall(graph: Graph, queries: np.ndarray,
     t in N(q) or N(t) meets N(q), so only the queries' and the truths'
     neighbour lists are needed (``hops`` is 1 or 2).
     """
-    return _recall(lambda nodes: _adjacency(graph, nodes), queries,
-                   true_neighbors, hops, k_cap)
-
-
-def slab_neighbor_recall(nbr: np.ndarray, queries: np.ndarray,
-                         true_neighbors: Sequence[np.ndarray], *,
-                         hops: int = 2, k_cap: Optional[int] = None) -> float:
-    """:func:`neighbor_recall` of ``Graph.from_degree_slabs(n, nbr, w)``,
-    computed from the (n, k) slab neighbour table without building the
-    graph — for slab tables too large to compact on the host."""
-    return _recall(lambda nodes: _slab_adjacency(np.asarray(nbr), nodes),
-                   queries, true_neighbors, hops, k_cap)
-
-
-def _recall(adjacency, queries, true_neighbors, hops, k_cap) -> float:
     queries = np.asarray(queries, np.int64)
     truths = [np.asarray(t, np.int64) for t in true_neighbors]
     wanted = [queries] + (truths if hops != 1 else [])
-    adj = adjacency(np.unique(np.concatenate(wanted)))
+    adj = _adjacency(graph, np.unique(np.concatenate(wanted)))
     ratios = []
     for q, truth in zip(queries, truths):
         if truth.size == 0:

@@ -206,14 +206,20 @@ _COMMON = """
 """
 
 
-# Independent hash / leader draws of the same corpus.  Affinity's Boruvka
-# rounds merge every cluster at once and stop wherever a round lands at or
-# below the target, so one draw's mesh-vs-host v-measure swings widely
-# (seeds 0-11 at p=1: 0.35-0.975, mean 0.75; the mesh recomputes true
-# average linkage, the host averages contracted means).  The parity claim
-# is about the expected labels, so it is asserted on the mean over draws;
-# the zero-gather accounting and exact components hold for every draw.
-AFFINITY_SEEDS = range(6)
+# Independent hash / leader draws of the same corpus, seed 7 among them.
+# The two Affinity paths pick identical merges for two rounds (both average
+# original edge weights), which every draw asserts exactly.  From round 3
+# the mesh recomputes true average linkage while the host averages
+# contracted means, and Boruvka rounds merge every cluster at once and stop
+# wherever a round lands at or below the target, so the final labels of one
+# draw swing widely: seeds 0-11 give mesh-vs-host v 0.35-0.975.  The lowest
+# is seed 7, where the mesh's fifth round lands at 7 clusters, one above the
+# target, and its sixth merges them to 2 (the host's fifth lands at 5); the
+# host path collapses alike at other draws (9 -> 3 at seeds 1 and 4).  So
+# each draw holds a floor of half the bar, and the bars themselves hold on
+# the mean over draws.
+AFFINITY_SEEDS = range(8)
+AFFINITY_DRAW_FLOOR = 0.3
 
 
 @pytest.mark.dist
@@ -245,7 +251,12 @@ def test_mesh_cluster_zero_gather_parity(devices):
             g = b.finalize()                   # the ONE edge fetch, AFTER
             host_cc = connected_components_np(g.n, g.src, g.dst)
             host_af = affinity_clustering(g, target_clusters=6)
+            two_rounds = [b.cluster("affinity", target_clusters=6,
+                                    max_rounds=2),
+                          affinity_clustering(g, target_clusters=6,
+                                              max_rounds=2)]
             draws.append({{
+                "two_rounds_equal": bool(np.array_equal(*two_rounds)),
                 "edge_fetches_before_labels": ts["edge_fetches"],
                 "edge_bytes_before_labels": ts["bytes"],
                 "a2a_bytes": ts["all_to_all_bytes"],
@@ -278,6 +289,10 @@ def test_mesh_cluster_zero_gather_parity(devices):
         assert d["a2a_calls"] > 0
         assert d["cc_exact"], d
         assert d["cc_converged"]
+        # Affinity: the same merges while both linkages coincide, then a
+        # per-draw floor on the final labels
+        assert d["two_rounds_equal"], d
+        assert d["v_mesh_vs_host"] >= AFFINITY_DRAW_FLOOR, d
     # v-measure parity with the host path (merge orders may differ; the
     # mesh recomputes true average linkage each round — see cluster_dist)
     mean = lambda key: float(np.mean([d[key] for d in res]))

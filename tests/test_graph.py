@@ -110,7 +110,7 @@ def _csr_recall(g, queries, truths, hops, k_cap):
 
 @pytest.mark.parametrize("hops,k_cap", [(1, None), (2, None), (2, 5)])
 def test_neighbor_recall_on_graph_and_slabs_matches_csr(hops, k_cap):
-    from repro.graph import neighbor_recall, slab_neighbor_recall
+    from repro.graph import neighbor_recall
     rs = np.random.RandomState(hops * 10 + (k_cap or 0))
     n, k = 80, 2
     nbr = rs.randint(-1, n, (n, k))
@@ -123,8 +123,6 @@ def test_neighbor_recall_on_graph_and_slabs_matches_csr(hops, k_cap):
     assert 0.0 < want < 1.0
     assert neighbor_recall(g, queries, truths, hops=hops,
                            k_cap=k_cap) == pytest.approx(want)
-    assert slab_neighbor_recall(nbr, queries, truths, hops=hops,
-                                k_cap=k_cap) == pytest.approx(want)
 
 
 def test_two_hop_sets():
