@@ -1921,9 +1921,9 @@ class GraphBuilder:
             graph's symmetric closure; labels are each component's min
             gid, identical to ``connected_components_np`` on the
             finalized graph.  Or ``"affinity"`` — sharded Boruvka /
-            average-Affinity; densified labels with v-measure parity
-            against the host ``affinity_clustering`` (merge orders may
-            differ — see cluster_dist's parity caveat).
+            average-Affinity; densified labels equal to the host
+            ``affinity_clustering`` on the finalized graph (see
+            cluster_dist's parity note).
           target_clusters / min_similarity: affinity stop knobs (as in
             ``affinity_clustering``); ignored by "components".
           max_rounds: label-round budget for either method.

@@ -48,14 +48,13 @@ All exchanges therefore default to ``capacity_factor = p`` (full capacity,
 drops impossible); at bench scale the buffers are small, and callers can
 trade headroom for wire volume once drop-tolerant variants matter.
 
-Parity caveat (tested, documented): the host ``affinity_clustering``
-re-averages already-averaged weights after each contraction
-(mean-of-means), while the mesh path recomputes each cluster pair's mean
-over the ORIGINAL slab weights every round — plus equal-weight ties break
-by smallest mate id instead of host edge-list order.  Merge sequences can
-therefore differ; the contract is v-measure parity (tests/test_cluster.py
-proves it at p=1/2/4), not label-for-label equality.  Connected components
-has no weights to average, so it IS exact.
+Parity: the host ``affinity_clustering`` also recomputes each cluster
+pair's mean over the ORIGINAL edge weights every round and breaks
+equal-weight ties by the smallest mate id, so both paths make the same
+merges and return the same labels (tests/test_cluster.py asserts it at
+p=1/2/4).  The means are float32 on the mesh and float64 sums on the host,
+so a tie that only the rounding decides could still split them.
+Connected components has no weights to average, so it is exact.
 """
 
 from __future__ import annotations

@@ -59,12 +59,15 @@ def affinity_clustering(graph: Graph, *, target_clusters: int = 1,
     """
     n = graph.n
     labels = np.arange(n, dtype=np.int64)
-    cu = graph.src.astype(np.int64).copy()
-    cv = graph.dst.astype(np.int64).copy()
-    w = graph.w.astype(np.float32).copy()
+    src = graph.src.astype(np.int64)
+    dst = graph.dst.astype(np.int64)
+    w0 = graph.w.astype(np.float32)
 
     for _ in range(max_rounds):
-        cu, cv, w = _contract_edges(cu, cv, w)
+        # every round averages the ORIGINAL edges between two clusters; a
+        # mean of the previous round's means would weight each merged
+        # sub-cluster pair equally, whatever its edge count
+        cu, cv, w = _contract_edges(labels[src], labels[dst], w0)
         if cu.size == 0:
             break
         live = np.unique(labels)
@@ -75,11 +78,12 @@ def affinity_clustering(graph: Graph, *, target_clusters: int = 1,
             cu, cv, w = cu[keep], cv[keep], w[keep]
             if cu.size == 0:
                 break
-        # Boruvka step: best incident edge per cluster.
+        # Boruvka step: best incident edge per cluster, the smallest mate
+        # id among equal weights.
         ends = np.concatenate([cu, cv])
         mates = np.concatenate([cv, cu])
         ww = np.concatenate([w, w])
-        order = np.lexsort((-ww, ends))
+        order = np.lexsort((mates, -ww, ends))
         ends_s, mates_s = ends[order], mates[order]
         first = np.ones(ends_s.size, bool)
         first[1:] = ends_s[1:] != ends_s[:-1]
@@ -97,7 +101,6 @@ def affinity_clustering(graph: Graph, *, target_clusters: int = 1,
                 break
             parent = new
         labels = parent[labels]
-        cu, cv = parent[cu], parent[cv]
 
     # Densify labels to 0..k-1
     _, labels = np.unique(labels, return_inverse=True)

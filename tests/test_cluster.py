@@ -19,9 +19,8 @@ Host half (runs in-process):
 Mesh half (``dist``-marked, forced-device subprocesses at p=1/2/4):
   * ``builder.cluster("components")`` labels are IDENTICAL to the host
     union-find on the finalized graph, at every shard count,
-  * ``builder.cluster("affinity")`` reaches v-measure parity with the
-    host ``affinity_clustering`` path (merge orders may differ — the
-    linkage recomputation caveat in cluster_dist's docstring),
+  * ``builder.cluster("affinity")`` labels are IDENTICAL to the host
+    ``affinity_clustering`` on the finalized graph,
   * the tentpole invariant: ``transfer_stats['edge_fetches']`` and
     ``['bytes']`` stay ZERO through any number of clusterings — labels
     are produced without a single global edge fetch; only the (n,) label
@@ -206,99 +205,72 @@ _COMMON = """
 """
 
 
-# Independent hash / leader draws of the same corpus, seed 7 among them.
-# The two Affinity paths pick identical merges for two rounds (both average
-# original edge weights), which every draw asserts exactly.  From round 3
-# the mesh recomputes true average linkage while the host averages
-# contracted means, and Boruvka rounds merge every cluster at once and stop
-# wherever a round lands at or below the target, so the final labels of one
-# draw swing widely: seeds 0-11 give mesh-vs-host v 0.35-0.975.  The lowest
-# is seed 7, where the mesh's fifth round lands at 7 clusters, one above the
-# target, and its sixth merges them to 2 (the host's fifth lands at 5); the
-# host path collapses alike at other draws (9 -> 3 at seeds 1 and 4).  So
-# each draw holds a floor of half the bar, and the bars themselves hold on
-# the mean over draws.
-AFFINITY_SEEDS = range(8)
-AFFINITY_DRAW_FLOOR = 0.3
-
-
 @pytest.mark.dist
 @pytest.mark.parametrize("devices", [1, 2, 4])
 def test_mesh_cluster_zero_gather_parity(devices):
     """The tentpole: labels at p=1/2/4 with zero edge fetches first.
 
-    components == host union-find exactly; affinity reaches v-measure
-    parity with the host path; transfer_stats prove nothing O(n*k) left
-    the device before the labels did.
+    components == host union-find exactly; affinity labels == the host
+    path's (both average original edge weights every round and break ties
+    by the smallest mate id); transfer_stats prove nothing O(n*k) left the
+    device before the labels did.
     """
     res = _run_sub(_COMMON + f"""
         feats, y = mnist_like_points(n=402, d=24, classes=6, spread=0.12,
                                      seed=0)
+        cfg = StarsConfig(mode="sorting", scoring="stars",
+                          family=HashFamilyConfig("simhash", m=16),
+                          measure="cosine", r=6, window=64, leaders=8,
+                          degree_cap=16, seed=7)
         mesh = jax.make_mesh(({devices},), ("data",))
-        draws = []
-        for seed in {list(AFFINITY_SEEDS)}:
-            cfg = StarsConfig(mode="sorting", scoring="stars",
-                              family=HashFamilyConfig("simhash", m=16),
-                              measure="cosine", r=6, window=64, leaders=8,
-                              degree_cap=16, seed=seed)
-            b = GraphBuilder(feats.dense, cfg, mesh=mesh)
-            b.add_reps(6)
-            acc_lib.reset_transfer_stats()
-            lab_cc, info_cc = b.cluster("components", return_info=True)
-            lab_af, info_af = b.cluster("affinity", target_clusters=6,
-                                        return_info=True)
-            ts = dict(acc_lib.transfer_stats)
-            g = b.finalize()                   # the ONE edge fetch, AFTER
-            host_cc = connected_components_np(g.n, g.src, g.dst)
-            host_af = affinity_clustering(g, target_clusters=6)
-            two_rounds = [b.cluster("affinity", target_clusters=6,
-                                    max_rounds=2),
-                          affinity_clustering(g, target_clusters=6,
-                                              max_rounds=2)]
-            draws.append({{
-                "two_rounds_equal": bool(np.array_equal(*two_rounds)),
-                "edge_fetches_before_labels": ts["edge_fetches"],
-                "edge_bytes_before_labels": ts["bytes"],
-                "a2a_bytes": ts["all_to_all_bytes"],
-                "a2a_calls": ts["all_to_all_calls"],
-                "label_fetches": ts["cluster_label_fetches"],
-                "label_bytes": ts["cluster_label_bytes"],
-                "cc_exact": bool(np.array_equal(lab_cc, host_cc)),
-                "cc_rounds": info_cc["rounds"],
-                "cc_converged": info_cc["converged"],
-                "af_rounds": info_af["rounds"],
-                "v_mesh_vs_host": v_measure(host_af, lab_af)["v"],
-                "v_host_truth": v_measure(y, host_af)["v"],
-                "v_mesh_truth": v_measure(y, lab_af)["v"],
-            }})
-        print(json.dumps({{"draws": draws}}))
-    """, devices)["draws"]
-    for d in res:
-        # ZERO global edge fetches before cluster labels — the tentpole
-        assert d["edge_fetches_before_labels"] == 0
-        assert d["edge_bytes_before_labels"] == 0
-        # the only device->host payload: two (n,) int32 label vectors
-        assert d["label_fetches"] == 2
-        assert d["label_bytes"] == 2 * 402 * 4
-        # label rounds ride the metered exchange idiom: cross-shard bytes
-        # are exactly 0 on one shard and real traffic beyond
-        if devices == 1:
-            assert d["a2a_bytes"] == 0
-        else:
-            assert d["a2a_bytes"] > 0
-        assert d["a2a_calls"] > 0
-        assert d["cc_exact"], d
-        assert d["cc_converged"]
-        # Affinity: the same merges while both linkages coincide, then a
-        # per-draw floor on the final labels
-        assert d["two_rounds_equal"], d
-        assert d["v_mesh_vs_host"] >= AFFINITY_DRAW_FLOOR, d
-    # v-measure parity with the host path (merge orders may differ; the
-    # mesh recomputes true average linkage each round — see cluster_dist)
-    mean = lambda key: float(np.mean([d[key] for d in res]))
-    assert len(res) == len(AFFINITY_SEEDS)
-    assert mean("v_mesh_vs_host") >= 0.6, res
-    assert mean("v_mesh_truth") >= mean("v_host_truth") - 0.15, res
+        b = GraphBuilder(feats.dense, cfg, mesh=mesh)
+        b.add_reps(6)
+        acc_lib.reset_transfer_stats()
+        lab_cc, info_cc = b.cluster("components", return_info=True)
+        lab_af, info_af = b.cluster("affinity", target_clusters=6,
+                                    return_info=True)
+        ts = dict(acc_lib.transfer_stats)
+        g = b.finalize()                       # the ONE edge fetch, AFTER
+        host_cc = connected_components_np(g.n, g.src, g.dst)
+        host_af = affinity_clustering(g, target_clusters=6)
+        out = {{
+            "edge_fetches_before_labels": ts["edge_fetches"],
+            "edge_bytes_before_labels": ts["bytes"],
+            "a2a_bytes": ts["all_to_all_bytes"],
+            "a2a_calls": ts["all_to_all_calls"],
+            "label_fetches": ts["cluster_label_fetches"],
+            "label_bytes": ts["cluster_label_bytes"],
+            "cc_exact": bool(np.array_equal(lab_cc, host_cc)),
+            "cc_rounds": info_cc["rounds"],
+            "cc_converged": info_cc["converged"],
+            "af_exact": bool(np.array_equal(lab_af, host_af)),
+            "af_rounds": info_af["rounds"],
+            "v_mesh_vs_host": v_measure(host_af, lab_af)["v"],
+            "v_host_truth": v_measure(y, host_af)["v"],
+            "v_mesh_truth": v_measure(y, lab_af)["v"],
+        }}
+        print(json.dumps(out))
+    """, devices)
+    # ZERO global edge fetches before cluster labels — the tentpole
+    assert res["edge_fetches_before_labels"] == 0
+    assert res["edge_bytes_before_labels"] == 0
+    # the only device->host payload: two (n,) int32 label vectors
+    assert res["label_fetches"] == 2
+    assert res["label_bytes"] == 2 * 402 * 4
+    # label rounds ride the metered exchange idiom: cross-shard bytes are
+    # exactly 0 on one shard and real traffic beyond
+    if devices == 1:
+        assert res["a2a_bytes"] == 0
+    else:
+        assert res["a2a_bytes"] > 0
+    assert res["a2a_calls"] > 0
+    assert res["cc_exact"], res
+    assert res["cc_converged"]
+    # Affinity: the same linkage and tie-break as the host path, so the
+    # same merges, round for round
+    assert res["af_exact"], res
+    assert res["v_mesh_vs_host"] >= 0.6, res
+    assert res["v_mesh_truth"] >= res["v_host_truth"] - 0.15, res
 
 
 @pytest.mark.dist

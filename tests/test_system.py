@@ -30,7 +30,7 @@ def _cfg(scoring, r=20, leaders=10, window=150, seed=7):
     # non-Stars quality" claim: at r=15/W=100 the affinity pipeline sat just
     # under the 0.8 VMeasure bar (0.784) while non-Stars scored 0.92+ — an
     # under-repetition artifact, not a Stars-vs-baseline gap.  At r=20/W=150
-    # both variants land at ~0.86-0.88 on average with a 7.4x comparison
+    # both variants land at ~0.84-0.87 on average with a 7.4x comparison
     # reduction, so the Fig. 1 ratio assertion keeps a wide margin too.
     return StarsConfig(mode="sorting", scoring=scoring,
                        family=HashFamilyConfig("simhash", m=20),
@@ -41,8 +41,8 @@ def _cfg(scoring, r=20, leaders=10, window=150, seed=7):
 # Independent hash / leader draws of the same corpus.  One draw's VMeasure
 # swings by up to +-0.1 for BOTH variants, because Boruvka rounds merge
 # every cluster at once and stop wherever the round lands at or below the
-# target (9 seeds at r=20: Stars 0.76-0.92, non-Stars 0.81-0.97, mean gap
-# 0.02; r=30 and r=40 spread as widely).  The Fig. 4 claim is about the
+# target (9 seeds at r=20: Stars 0.76-0.94, non-Stars 0.78-0.90, mean gap
+# 0.03).  The Fig. 4 claim is about the
 # expected quality, so it is asserted on the mean over draws.
 QUALITY_SEEDS = range(6)
 
