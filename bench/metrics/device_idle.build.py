@@ -1,0 +1,7 @@
+"""Share of the build window in which no op ran on the device (%)."""
+
+
+def read(run):
+    if not run.trace.ops:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
