@@ -59,6 +59,7 @@ Design points:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Callable, Dict, List, Optional, Union
@@ -67,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.core import lsh as lsh_lib
 from repro.core.spanner import Graph
 from repro.core.stars import StarsConfig, _prefilter_sketch, _rep_candidates
@@ -108,6 +110,15 @@ def as_feature_store(features: FeaturesLike,
 # --------------------------------------------------------------------------- #
 # Candidate sources (single-device)
 # --------------------------------------------------------------------------- #
+
+
+def _bind_span(key, miss: bool):
+    """``stars.bind`` around a round whose program ``key`` a backend has
+    not bound yet: the bind and the round's first call, which compiles
+    the program or loads it from the compilation cache."""
+    if not miss:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(scopes.BIND, key=repr(key))
 
 
 class RepetitionSource:
@@ -368,19 +379,20 @@ class _SingleDeviceBackend:
                   refresh_probs=None):
         self.ensure_measure_state()
         key = (new_from, refresh_below, refresh_fraction)
-        if key not in self._bound:
-            mstate = (self.store.state_table
-                      if self.measure.state_width is not None else None)
-            self._bound[key] = self.source.bind(
-                self.features, new_from, refresh_below, refresh_fraction,
-                measure_state=mstate,
-                cache_slots=(self._pair_cache.slots
-                             if self._pair_cache is not None else 0))
-        if self._pair_cache is not None:
-            state, counters, self._pair_cache = self._bound[key](
-                state, rep_index, refresh_probs, self._pair_cache)
-            return state, counters
-        return self._bound[key](state, rep_index, refresh_probs)
+        with _bind_span(key, key not in self._bound):
+            if key not in self._bound:
+                mstate = (self.store.state_table
+                          if self.measure.state_width is not None else None)
+                self._bound[key] = self.source.bind(
+                    self.features, new_from, refresh_below, refresh_fraction,
+                    measure_state=mstate,
+                    cache_slots=(self._pair_cache.slots
+                                 if self._pair_cache is not None else 0))
+            if self._pair_cache is not None:
+                state, counters, self._pair_cache = self._bound[key](
+                    state, rep_index, refresh_probs, self._pair_cache)
+                return state, counters
+            return self._bound[key](state, rep_index, refresh_probs)
 
     def extend(self, new_features: PointFeatures) -> None:
         self.store.append(new_features)
@@ -476,6 +488,7 @@ def _stream_sketch_words(store: PagedFeatureStore, cfg: StarsConfig, rep,
     fn = words_fns.get(chunk)
     if fn is None:
         @jax.jit
+        @scopes.scoped(scopes.SKETCH)
         def words_chunk(x, rep):
             rep_seed = jnp.asarray(rep, jnp.uint32) ^ jnp.uint32(cfg.seed)
             return lsh_lib.sketch(PointFeatures(dense=x), cfg.family,
@@ -698,40 +711,44 @@ class _PagedBackend:
                 raise ValueError("the exact 'allpairs' source has no "
                                  "sampling staleness to refresh")
             return self._run_allpairs(state, new_from)
-        rep = jnp.int32(rep_index)
-        words = _stream_sketch_words(self.store, self.cfg, rep,
-                                     self._words_fns, self.store.n)
-        win = self._win_fn()(words, rep)
-        nw = int(win.gid.shape[0])
+        nw = _refresh_window_count(self.cfg, self.store.n)
         C = self._chunk_rows(nw)
-        pad = (-nw) % C
-        gid = jnp.pad(win.gid, ((0, pad), (0, 0)), constant_values=-1)
-        valid = jnp.pad(win.valid, ((0, pad), (0, 0)))
-        bucket = jnp.pad(win.bucket, ((0, pad), (0, 0)),
-                         constant_values=np.uint32(0xFFFFFFFF))
-        probs = ()
-        if refresh_below > 0:
-            if refresh_probs is None:
-                refresh_probs = jnp.full((nw,), refresh_fraction,
-                                         jnp.float32)
-            probs = (jnp.asarray(refresh_probs, jnp.float32),)
-        chunk_fn = self._bind_chunk(C, nw, new_from, refresh_below,
-                                    refresh_fraction)
-        has_state = self.measure.state_width is not None
-        per_chunk = []
-        for c0 in range(0, nw, C):
-            gid_c = gid[c0:c0 + C]
-            gid_np = np.asarray(jax.device_get(gid_c))
-            block = self.store.gather(gid_np).dense
-            extra = ((self.store.gather_state(gid_np),)
-                     if has_state else ())
-            state, cnt = chunk_fn(state, block, gid_c,
-                                  valid[c0:c0 + C], bucket[c0:c0 + C],
-                                  rep, jnp.int32(c0), *extra, *probs)
-            per_chunk.append(cnt)
-        counters = {k: jnp.concatenate([jnp.ravel(c[k]) for c in per_chunk])
-                    for k in per_chunk[0]}
-        return state, counters
+        key = (C, nw, new_from, refresh_below, refresh_fraction)
+        with _bind_span(key, self.store.n not in self._win_fns
+                        or key not in self._chunk_fns):
+            rep = jnp.int32(rep_index)
+            words = _stream_sketch_words(self.store, self.cfg, rep,
+                                         self._words_fns, self.store.n)
+            win = self._win_fn()(words, rep)
+            pad = (-nw) % C
+            gid = jnp.pad(win.gid, ((0, pad), (0, 0)), constant_values=-1)
+            valid = jnp.pad(win.valid, ((0, pad), (0, 0)))
+            bucket = jnp.pad(win.bucket, ((0, pad), (0, 0)),
+                             constant_values=np.uint32(0xFFFFFFFF))
+            probs = ()
+            if refresh_below > 0:
+                if refresh_probs is None:
+                    refresh_probs = jnp.full((nw,), refresh_fraction,
+                                             jnp.float32)
+                probs = (jnp.asarray(refresh_probs, jnp.float32),)
+            chunk_fn = self._bind_chunk(C, nw, new_from, refresh_below,
+                                        refresh_fraction)
+            has_state = self.measure.state_width is not None
+            per_chunk = []
+            for c0 in range(0, nw, C):
+                gid_c = gid[c0:c0 + C]
+                gid_np = np.asarray(jax.device_get(gid_c))
+                block = self.store.gather(gid_np).dense
+                extra = ((self.store.gather_state(gid_np),)
+                         if has_state else ())
+                state, cnt = chunk_fn(state, block, gid_c,
+                                      valid[c0:c0 + C], bucket[c0:c0 + C],
+                                      rep, jnp.int32(c0), *extra, *probs)
+                per_chunk.append(cnt)
+            counters = {k: jnp.concatenate([jnp.ravel(c[k])
+                                            for c in per_chunk])
+                        for k in per_chunk[0]}
+            return state, counters
 
     # -- the exact blocked sweep ---------------------------------------- #
     def _run_allpairs(self, state, new_from: int):
@@ -1037,6 +1054,7 @@ class _MeshBackend:
         n = self._n
 
         @jax.jit
+        @scopes.scoped(scopes.SKETCH)
         def sketch_phase(x, rep):
             rep_seed = jnp.asarray(rep, jnp.uint32) ^ jnp.uint32(cfg.seed)
             words = lsh_lib.sketch(PointFeatures(dense=x), cfg.family,
@@ -1054,6 +1072,7 @@ class _MeshBackend:
         n = self._n
 
         @jax.jit
+        @scopes.scoped(scopes.SKETCH)
         def keys_phase(words, rep):
             return _sketch_keys(cfg, n, words, rep)
 
@@ -1244,32 +1263,35 @@ class _MeshBackend:
                   refresh_probs=None):
         from repro.distributed.stars_dist import (accumulate_all_to_all,
                                                   fetch_rows_all_to_all)
-        _, _, fetch_table, score_fn = self._bind(
-            new_from, refresh_below, refresh_fraction)
-        rep = jnp.int32(rep_index)
-        blk_gid, blk_bucket, drop_sort = self._sort_round(rep)
-        if self._paged:
-            rows, rows_ok = self._fetch_rows_paged(blk_gid)
-            drop_fetch = jnp.zeros((1,), jnp.int32)
-        else:
-            rows, rows_ok, drop_fetch = fetch_rows_all_to_all(
-                fetch_table, blk_gid, mesh=self.mesh, axis=self.axis,
-                capacity_factor=self.FETCH_CAPACITY_FACTOR)
-        probs = self._probs_arg(refresh_below, refresh_fraction,
-                                refresh_probs)
-        (src, dst, wts, emit, comparisons, emitted, pref_ops,
-         scored) = score_fn(blk_gid, blk_bucket, rows, rows_ok, rep, *probs)
-        state, drop_emit = accumulate_all_to_all(
-            state, src, dst, wts, emit,
-            mesh=self.mesh, axis=self.axis,
-            capacity_factor=self.EMIT_CAPACITY_FACTOR,
-            exact_weights=self.cfg.exact_weights)
-        counters = {"comparisons": comparisons, "emitted": emitted,
-                    "prefilter_ops": pref_ops, "scored_windows": scored}
-        counters["dropped"] = jnp.concatenate(
-            [jnp.ravel(drop_sort), jnp.ravel(drop_fetch),
-             jnp.ravel(drop_emit)])
-        return state, counters
+        key = (self._n, new_from, refresh_below, refresh_fraction)
+        with _bind_span(key, key not in self._bound):
+            _, _, fetch_table, score_fn = self._bind(
+                new_from, refresh_below, refresh_fraction)
+            rep = jnp.int32(rep_index)
+            blk_gid, blk_bucket, drop_sort = self._sort_round(rep)
+            if self._paged:
+                rows, rows_ok = self._fetch_rows_paged(blk_gid)
+                drop_fetch = jnp.zeros((1,), jnp.int32)
+            else:
+                rows, rows_ok, drop_fetch = fetch_rows_all_to_all(
+                    fetch_table, blk_gid, mesh=self.mesh, axis=self.axis,
+                    capacity_factor=self.FETCH_CAPACITY_FACTOR)
+            probs = self._probs_arg(refresh_below, refresh_fraction,
+                                    refresh_probs)
+            (src, dst, wts, emit, comparisons, emitted, pref_ops,
+             scored) = score_fn(blk_gid, blk_bucket, rows, rows_ok, rep,
+                                *probs)
+            state, drop_emit = accumulate_all_to_all(
+                state, src, dst, wts, emit,
+                mesh=self.mesh, axis=self.axis,
+                capacity_factor=self.EMIT_CAPACITY_FACTOR,
+                exact_weights=self.cfg.exact_weights)
+            counters = {"comparisons": comparisons, "emitted": emitted,
+                        "prefilter_ops": pref_ops, "scored_windows": scored}
+            counters["dropped"] = jnp.concatenate(
+                [jnp.ravel(drop_sort), jnp.ravel(drop_fetch),
+                 jnp.ravel(drop_emit)])
+            return state, counters
 
     def run_round_pair(self, state, rep_index: int, new_from: int,
                        refresh_below: int = 0, refresh_fraction: float = 1.0,
@@ -1305,35 +1327,39 @@ class _MeshBackend:
                 state, rep_index + 1, new_from, refresh_below,
                 refresh_fraction, refresh_probs[1])
             return state, counters_a, counters_b
-        _, _, fetch_table, score_fn = self._bind(
-            new_from, refresh_below, refresh_fraction)
-        rep_a, rep_b = jnp.int32(rep_index), jnp.int32(rep_index + 1)
-        gid_a, bucket_a, drop_sort_a = self._sort_round(rep_a)
-        gid_b, bucket_b, drop_sort_b = self._sort_round(rep_b)
-        (rows_a, rows_b), (ok_a, ok_b), drop_fetch = fetch_rows_all_to_all(
-            fetch_table, (gid_a, gid_b), mesh=self.mesh, axis=self.axis,
-            capacity_factor=self.FETCH_CAPACITY_FACTOR)
-        probs_a = self._probs_arg(refresh_below, refresh_fraction,
-                                  refresh_probs[0])
-        probs_b = self._probs_arg(refresh_below, refresh_fraction,
-                                  refresh_probs[1])
-        out_a = score_fn(gid_a, bucket_a, rows_a, ok_a, rep_a, *probs_a)
-        out_b = score_fn(gid_b, bucket_b, rows_b, ok_b, rep_b, *probs_b)
-        state, drop_emit = accumulate_all_to_all(
-            state, (out_a[0], out_b[0]), (out_a[1], out_b[1]),
-            (out_a[2], out_b[2]), (out_a[3], out_b[3]),
-            mesh=self.mesh, axis=self.axis,
-            capacity_factor=self.EMIT_CAPACITY_FACTOR,
-            exact_weights=self.cfg.exact_weights)
-        counters_a = {"comparisons": out_a[4], "emitted": out_a[5],
-                      "prefilter_ops": out_a[6], "scored_windows": out_a[7],
-                      "dropped": jnp.concatenate(
-                          [jnp.ravel(drop_sort_a), jnp.ravel(drop_fetch),
-                           jnp.ravel(drop_emit)])}
-        counters_b = {"comparisons": out_b[4], "emitted": out_b[5],
-                      "prefilter_ops": out_b[6], "scored_windows": out_b[7],
-                      "dropped": jnp.ravel(drop_sort_b)}
-        return state, counters_a, counters_b
+        key = (self._n, new_from, refresh_below, refresh_fraction)
+        with _bind_span(key, key not in self._bound):
+            _, _, fetch_table, score_fn = self._bind(
+                new_from, refresh_below, refresh_fraction)
+            rep_a, rep_b = jnp.int32(rep_index), jnp.int32(rep_index + 1)
+            gid_a, bucket_a, drop_sort_a = self._sort_round(rep_a)
+            gid_b, bucket_b, drop_sort_b = self._sort_round(rep_b)
+            (rows_a, rows_b), (ok_a, ok_b), drop_fetch = fetch_rows_all_to_all(
+                fetch_table, (gid_a, gid_b), mesh=self.mesh, axis=self.axis,
+                capacity_factor=self.FETCH_CAPACITY_FACTOR)
+            probs_a = self._probs_arg(refresh_below, refresh_fraction,
+                                      refresh_probs[0])
+            probs_b = self._probs_arg(refresh_below, refresh_fraction,
+                                      refresh_probs[1])
+            out_a = score_fn(gid_a, bucket_a, rows_a, ok_a, rep_a, *probs_a)
+            out_b = score_fn(gid_b, bucket_b, rows_b, ok_b, rep_b, *probs_b)
+            state, drop_emit = accumulate_all_to_all(
+                state, (out_a[0], out_b[0]), (out_a[1], out_b[1]),
+                (out_a[2], out_b[2]), (out_a[3], out_b[3]),
+                mesh=self.mesh, axis=self.axis,
+                capacity_factor=self.EMIT_CAPACITY_FACTOR,
+                exact_weights=self.cfg.exact_weights)
+            counters_a = {"comparisons": out_a[4], "emitted": out_a[5],
+                          "prefilter_ops": out_a[6],
+                          "scored_windows": out_a[7],
+                          "dropped": jnp.concatenate(
+                              [jnp.ravel(drop_sort_a), jnp.ravel(drop_fetch),
+                               jnp.ravel(drop_emit)])}
+            counters_b = {"comparisons": out_b[4], "emitted": out_b[5],
+                          "prefilter_ops": out_b[6],
+                          "scored_windows": out_b[7],
+                          "dropped": jnp.ravel(drop_sort_b)}
+            return state, counters_a, counters_b
 
     def extend(self, new_features: PointFeatures) -> None:
         if self._paged:
@@ -1768,34 +1794,36 @@ class GraphBuilder:
         pair_fn = getattr(self._backend, "run_round_pair", None)
         done = 0
         while done < reps:
-            rep0 = self._reps_done
-            if pair_fn is not None and reps - done >= 2:
-                # coalesced repetition pair (mesh backend): the refresh
-                # probability vectors are computed SEQUENTIALLY — the
-                # second round's bias sees the first round's host-side
-                # age advance, exactly as two unpaired rounds would
-                probs = (self._next_refresh_probs(rep0, refresh_fraction)
-                         if refresh else None,
-                         self._next_refresh_probs(rep0 + 1, refresh_fraction)
-                         if refresh else None)
-                self._state, counters_a, counters_b = pair_fn(
-                    self._state, rep0, new_from,
-                    refresh_below=refresh_below,
-                    refresh_fraction=refresh_fraction,
-                    refresh_probs=probs)
-                self._note_round(counters_a, refresh, progress)
-                self._note_round(counters_b, refresh, progress)
-                done += 2
-            else:
-                probs = (self._next_refresh_probs(rep0, refresh_fraction)
-                         if refresh else None)
-                self._state, counters = self._backend.run_round(
-                    self._state, rep0, new_from,
-                    refresh_below=refresh_below,
-                    refresh_fraction=refresh_fraction,
-                    refresh_probs=probs)
-                self._note_round(counters, refresh, progress)
-                done += 1
+            with jax.profiler.TraceAnnotation(scopes.ROUND):
+                rep0 = self._reps_done
+                if pair_fn is not None and reps - done >= 2:
+                    # coalesced repetition pair (mesh backend): the refresh
+                    # probability vectors are computed SEQUENTIALLY — the
+                    # second round's bias sees the first round's host-side
+                    # age advance, exactly as two unpaired rounds would
+                    probs = (self._next_refresh_probs(rep0, refresh_fraction)
+                             if refresh else None,
+                             self._next_refresh_probs(rep0 + 1,
+                                                      refresh_fraction)
+                             if refresh else None)
+                    self._state, counters_a, counters_b = pair_fn(
+                        self._state, rep0, new_from,
+                        refresh_below=refresh_below,
+                        refresh_fraction=refresh_fraction,
+                        refresh_probs=probs)
+                    self._note_round(counters_a, refresh, progress)
+                    self._note_round(counters_b, refresh, progress)
+                    done += 2
+                else:
+                    probs = (self._next_refresh_probs(rep0, refresh_fraction)
+                             if refresh else None)
+                    self._state, counters = self._backend.run_round(
+                        self._state, rep0, new_from,
+                        refresh_below=refresh_below,
+                        refresh_fraction=refresh_fraction,
+                        refresh_probs=probs)
+                    self._note_round(counters, refresh, progress)
+                    done += 1
 
     def _note_round(self, counters: Dict, refresh: bool,
                     progress: Optional[Callable[[int], None]]) -> None:
@@ -1849,11 +1877,13 @@ class GraphBuilder:
         cap = max(self._capacity,
                   self.cfg.slab_capacity(n, reps=max(reps_total, 1)))
         if self._state is None:
-            self._capacity = cap
-            self._state = self._backend.init_state(cap)
+            with jax.profiler.TraceAnnotation(scopes.GROW):
+                self._capacity = cap
+                self._state = self._backend.init_state(cap)
         elif n > self._state.n or cap > self._capacity:
-            self._state = self._backend.grow_state(self._state, n, cap)
-            self._capacity = cap
+            with jax.profiler.TraceAnnotation(scopes.GROW):
+                self._state = self._backend.grow_state(self._state, n, cap)
+                self._capacity = cap
 
     def _ensure_state(self) -> acc_lib.EdgeAccumulator:
         if self._state is None:
@@ -1863,7 +1893,9 @@ class GraphBuilder:
     # ------------------------------------------------------------------ #
     def _merged_stats(self) -> Dict[str, int]:
         totals = dict(self._stats_base)
-        for counters in jax.device_get(self._counters):
+        with jax.profiler.TraceAnnotation(scopes.COUNTERS):
+            pending = jax.device_get(self._counters)
+        for counters in pending:
             for key, val in counters.items():
                 totals[key] = totals.get(key, 0) + int(
                     np.sum(np.asarray(val, np.int64)))

@@ -44,6 +44,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import lsh as lsh_lib
 from repro.core import windows as win_lib
 from repro.core.spanner import Graph
@@ -429,6 +430,7 @@ def _rep_keys(cfg: StarsConfig, rep_index: jax.Array):
     return k_tie, k_shift, k_lead, k_refresh
 
 
+@scopes.scoped(scopes.WINDOWS)
 def _rep_window_grid(cfg: StarsConfig, words: jax.Array,
                      k_tie: jax.Array,
                      k_shift: jax.Array) -> win_lib.Windows:
@@ -491,10 +493,11 @@ def _rep_candidates(cfg: StarsConfig, features: PointFeatures,
     ``GraphBuilder.refresh_reps``.  The two masks are mutually exclusive
     per round.
     """
-    rep_seed = jnp.asarray(rep_index, jnp.uint32) ^ jnp.uint32(cfg.seed)
-    k_tie, k_shift, k_lead, k_refresh = _rep_keys(cfg, rep_index)
+    with jax.named_scope(scopes.SKETCH):
+        rep_seed = jnp.asarray(rep_index, jnp.uint32) ^ jnp.uint32(cfg.seed)
+        k_tie, k_shift, k_lead, k_refresh = _rep_keys(cfg, rep_index)
 
-    words = lsh_lib.sketch(features, cfg.family, rep_seed=rep_seed)
+        words = lsh_lib.sketch(features, cfg.family, rep_seed=rep_seed)
     win = _rep_window_grid(cfg, words, k_tie, k_shift)
 
     return _score_windows(cfg, features, measure_fn, prefilter, win, k_lead,
@@ -504,6 +507,7 @@ def _rep_candidates(cfg: StarsConfig, features: PointFeatures,
                           state=state)
 
 
+@scopes.scoped(scopes.SCORE)
 def _score_windows(cfg: StarsConfig, features: Optional[PointFeatures],
                    measure_fn, prefilter, win: win_lib.Windows,
                    k_lead: jax.Array, *, new_from: int = 0,

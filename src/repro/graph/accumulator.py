@@ -38,6 +38,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.kernels import ops as kernel_ops
 
 _BIG = jnp.int32(2**31 - 1)
@@ -246,16 +247,17 @@ def accumulate(state: EdgeAccumulator, src: jax.Array, dst: jax.Array,
     is inserted under both endpoints, so the final union over slabs contains
     an edge iff it ranks top-k for at least one endpoint.
     """
-    src = src.ravel().astype(jnp.int32)
-    dst = dst.ravel().astype(jnp.int32)
-    w = w.ravel().astype(jnp.float32)
-    ok = valid.ravel() & (src >= 0) & (dst >= 0) & (src != dst)
+    with jax.named_scope(scopes.FOLD_DEDUP):
+        src = src.ravel().astype(jnp.int32)
+        dst = dst.ravel().astype(jnp.int32)
+        w = w.ravel().astype(jnp.float32)
+        ok = valid.ravel() & (src >= 0) & (dst >= 0) & (src != dst)
 
-    # one instance per endpoint: insert (dst, w) under src and vice versa
-    node = jnp.concatenate([src, dst])
-    nbr = jnp.concatenate([dst, src])
-    ww = jnp.concatenate([w, w])
-    ok2 = jnp.concatenate([ok, ok])
+        # one instance per endpoint: insert (dst, w) under src and vice versa
+        node = jnp.concatenate([src, dst])
+        nbr = jnp.concatenate([dst, src])
+        ww = jnp.concatenate([w, w])
+        ok2 = jnp.concatenate([ok, ok])
     return _fold_triples(state, node, nbr, ww, ok2)
 
 
@@ -281,82 +283,87 @@ def _fold_triples(state: EdgeAccumulator, node: jax.Array, nbr: jax.Array,
     the mesh's sharded per-shard folds (each shard bumps only its own row
     block, exactly like the slab data itself).
     """
-    n, cap = state.nbr.shape
-    node = node.astype(jnp.int32)
-    nbr = nbr.astype(jnp.int32)
-    ww = ww.astype(jnp.float32)
-    # NB: no node != nbr check here — self-loop exclusion happens on GLOBAL
-    # ids in the caller (``node`` may be in shard-row coordinates).
-    ok2 = ok2 & (node >= 0) & (nbr >= 0)
-    m2 = node.shape[0]
-    kin = min(cap, m2)
+    with jax.named_scope(scopes.FOLD_DEDUP):
+        n, cap = state.nbr.shape
+        node = node.astype(jnp.int32)
+        nbr = nbr.astype(jnp.int32)
+        ww = ww.astype(jnp.float32)
+        # NB: no node != nbr check here — self-loop exclusion happens on
+        # GLOBAL ids in the caller (``node`` may be in shard-row
+        # coordinates).
+        ok2 = ok2 & (node >= 0) & (nbr >= 0)
+        m2 = node.shape[0]
+        kin = min(cap, m2)
 
-    node_k = jnp.where(ok2, node, _BIG)
-    nbr_k = jnp.where(ok2, nbr, _BIG)
-    negw = jnp.where(ok2, -ww, jnp.inf)
+        node_k = jnp.where(ok2, node, _BIG)
+        nbr_k = jnp.where(ok2, nbr, _BIG)
+        negw = jnp.where(ok2, -ww, jnp.inf)
 
-    # 1) dedup within the batch: group by (node, nbr), heaviest instance
-    #    first; later instances of a group are dropped.
-    node_s, nbr_s, negw_s = jax.lax.sort((node_k, nbr_k, negw), num_keys=3)
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool),
-         (node_s[1:] != node_s[:-1]) | (nbr_s[1:] != nbr_s[:-1])])
-    keep = first & (node_s != _BIG)
+        # 1) dedup within the batch: group by (node, nbr), heaviest instance
+        #    first; later instances of a group are dropped.
+        node_s, nbr_s, negw_s = jax.lax.sort((node_k, nbr_k, negw), num_keys=3)
+        first = jnp.concatenate(
+            [jnp.ones((1,), bool),
+             (node_s[1:] != node_s[:-1]) | (nbr_s[1:] != nbr_s[:-1])])
+        keep = first & (node_s != _BIG)
 
-    # 2) bucket: per-node rank by weight, scatter the top kin of each node
-    #    into fixed (n, kin) candidate rows.  Candidates beyond rank kin
-    #    (>= cap) can never enter the final top-cap, so dropping them here
-    #    is exact.
-    node_k2 = jnp.where(keep, node_s, _BIG)
-    negw2 = jnp.where(keep, negw_s, jnp.inf)
-    nbr_k2 = jnp.where(keep, nbr_s, _BIG)
-    iota1 = jnp.arange(m2, dtype=jnp.int32)
-    node_f, negw_f, nbr_f, p1 = jax.lax.sort(
-        (node_k2, negw2, nbr_k2, iota1), num_keys=3)
-    starts = jnp.searchsorted(node_f, jnp.arange(n, dtype=jnp.int32))
-    live = node_f != _BIG
-    node_c = jnp.where(live, node_f, 0)
-    rank = jnp.arange(m2, dtype=jnp.int32) - starts[node_c].astype(jnp.int32)
-    slot = jnp.where(live & (rank < kin), rank, kin)     # kin -> dropped
-    inc_nbr = jnp.full((n, kin), -1, jnp.int32).at[node_c, slot].set(
-        nbr_f, mode="drop")
-    inc_w = jnp.full((n, kin), -jnp.inf, jnp.float32).at[node_c, slot].set(
-        -negw_f, mode="drop")
+    with jax.named_scope(scopes.FOLD_BUCKET):
+        # 2) bucket: per-node rank by weight, scatter the top kin of each node
+        #    into fixed (n, kin) candidate rows.  Candidates beyond rank kin
+        #    (>= cap) can never enter the final top-cap, so dropping them here
+        #    is exact.
+        node_k2 = jnp.where(keep, node_s, _BIG)
+        negw2 = jnp.where(keep, negw_s, jnp.inf)
+        nbr_k2 = jnp.where(keep, nbr_s, _BIG)
+        iota1 = jnp.arange(m2, dtype=jnp.int32)
+        node_f, negw_f, nbr_f, p1 = jax.lax.sort(
+            (node_k2, negw2, nbr_k2, iota1), num_keys=3)
+        starts = jnp.searchsorted(node_f, jnp.arange(n, dtype=jnp.int32))
+        live = node_f != _BIG
+        node_c = jnp.where(live, node_f, 0)
+        rank = (jnp.arange(m2, dtype=jnp.int32)
+                - starts[node_c].astype(jnp.int32))
+        slot = jnp.where(live & (rank < kin), rank, kin)     # kin -> dropped
+        inc_nbr = jnp.full((n, kin), -1, jnp.int32).at[node_c, slot].set(
+            nbr_f, mode="drop")
+        inc_w = jnp.full((n, kin), -jnp.inf, jnp.float32).at[node_c, slot].set(
+            -negw_f, mode="drop")
 
-    # 2b) CPU only: nbr-ascending companion view of the same survivors, so
-    #     the merge-path slab merge needs no sort at all (the step-1 order
-    #     is already (node, nbr); a few stream-length scatters re-express
-    #     it per node row).  TPU skips this — the Pallas kernel dedups in
-    #     VMEM and never reads the companion view.
-    presorted = None
-    if not kernel_ops.pallas_by_default():
-        # weight-order slot of every step-1 element (kin == dropped/dead)
-        wrank1 = jnp.zeros((m2,), jnp.int32).at[p1].set(slot)
-        surv1 = (wrank1 < kin).astype(jnp.int32)
-        excl = jnp.cumsum(surv1) - surv1                 # survivors before e
-        starts1 = jnp.searchsorted(node_s, jnp.arange(n, dtype=jnp.int32))
-        node1 = jnp.where(node_s != _BIG, node_s, 0)
-        nbr_rank = excl - excl[starts1[node1]]           # rank among node's
-        slot_bn = jnp.where(surv1 == 1, nbr_rank, kin)   # survivors, by nbr
-        nbr_bn = jnp.full((n, kin), _BIG, jnp.int32).at[node1, slot_bn].set(
-            nbr_s, mode="drop")
-        negw_bn = jnp.full((n, kin), jnp.inf, jnp.float32).at[
-            node1, slot_bn].set(negw_s, mode="drop")
-        idx_bn = jnp.full((n, kin), kin, jnp.int32).at[node1, slot_bn].set(
-            wrank1, mode="drop")
-        presorted = (nbr_bn, negw_bn, idx_bn)
+        # 2b) CPU only: nbr-ascending companion view of the same survivors, so
+        #     the merge-path slab merge needs no sort at all (the step-1 order
+        #     is already (node, nbr); a few stream-length scatters re-express
+        #     it per node row).  TPU skips this — the Pallas kernel dedups in
+        #     VMEM and never reads the companion view.
+        presorted = None
+        if not kernel_ops.pallas_by_default():
+            # weight-order slot of every step-1 element (kin == dropped/dead)
+            wrank1 = jnp.zeros((m2,), jnp.int32).at[p1].set(slot)
+            surv1 = (wrank1 < kin).astype(jnp.int32)
+            excl = jnp.cumsum(surv1) - surv1             # survivors before e
+            starts1 = jnp.searchsorted(node_s, jnp.arange(n, dtype=jnp.int32))
+            node1 = jnp.where(node_s != _BIG, node_s, 0)
+            nbr_rank = excl - excl[starts1[node1]]       # rank among node's
+            slot_bn = jnp.where(surv1 == 1, nbr_rank, kin)  # survivors, by nbr
+            nbr_bn = jnp.full((n, kin), _BIG, jnp.int32).at[
+                node1, slot_bn].set(nbr_s, mode="drop")
+            negw_bn = jnp.full((n, kin), jnp.inf, jnp.float32).at[
+                node1, slot_bn].set(negw_s, mode="drop")
+            idx_bn = jnp.full((n, kin), kin, jnp.int32).at[node1, slot_bn].set(
+                wrank1, mode="drop")
+            presorted = (nbr_bn, negw_bn, idx_bn)
 
-    # 3) merge into the running slabs (Pallas on TPU; sort-free merge-path
-    #    jnp ref on CPU — both sides are weight-sorted and deduped by
-    #    construction)
-    new_nbr, new_w = kernel_ops.topk_merge(state.nbr, state.w, inc_nbr, inc_w,
-                                           sorted_inputs=True,
-                                           inc_presorted=presorted)
-    # exact per-row change detection (empty slots compare equal: -1 == -1,
-    # and -inf == -inf is True in IEEE) -> bump changed rows' versions
-    changed = jnp.any((new_nbr != state.nbr) | (new_w != state.w), axis=1)
-    return EdgeAccumulator(nbr=new_nbr, w=new_w,
-                           ver=state.ver + changed.astype(jnp.int32))
+    with jax.named_scope(scopes.FOLD_MERGE):
+        # 3) merge into the running slabs (Pallas on TPU; sort-free merge-path
+        #    jnp ref on CPU — both sides are weight-sorted and deduped by
+        #    construction)
+        new_nbr, new_w = kernel_ops.topk_merge(
+            state.nbr, state.w, inc_nbr, inc_w, sorted_inputs=True,
+            inc_presorted=presorted)
+        # exact per-row change detection (empty slots compare equal: -1 == -1,
+        # and -inf == -inf is True in IEEE) -> bump changed rows' versions
+        changed = jnp.any((new_nbr != state.nbr) | (new_w != state.w), axis=1)
+        return EdgeAccumulator(nbr=new_nbr, w=new_w,
+                               ver=state.ver + changed.astype(jnp.int32))
 
 
 def to_graph(state: EdgeAccumulator, *,
