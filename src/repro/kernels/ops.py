@@ -105,6 +105,10 @@ def topk_merge(slab_nbr, slab_w, inc_nbr, inc_w, *,
                inc_presorted=None):
     """Per-node top-k degree-slab merge (the edge-accumulator update).
 
+    The Pallas kernel sorts each staged row of K = k + kin lanes (padded
+    to a power of two) with two bitonic sorting networks in VMEM, by
+    neighbour to drop duplicates and then by weight to rank: O(K log^2 K)
+    compare-exchanges on static lane shifts (kernels/topk_merge.py).
     ``sorted_inputs=True`` asserts the accumulator-traffic preconditions
     (rows weight-sorted descending, per-row deduped, -1/-inf tails) and
     routes the CPU path to the merge-path formulation instead of the full

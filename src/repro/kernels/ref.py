@@ -110,8 +110,10 @@ def topk_merge_ref(slab_nbr: jax.Array, slab_w: jax.Array,
     slots.  Per row: dedup by neighbour keeping max weight, then keep the k
     heaviest survivors sorted by (weight desc, nbr asc).
 
-    Sort-based formulation — O(K log K) per row instead of the kernel's
-    O(K^2) VMEM matrices, which is the right trade-off for the CPU path.
+    Two stable row sorts, O(K log K) per row, K = k + kin.  The Pallas
+    kernel runs the same two orderings as bitonic sorting networks in VMEM
+    (O(K log^2 K) compare-exchanges on static lane shifts, kernels/
+    topk_merge.py) and matches this function bit for bit.
     """
     big = jnp.int32(2**31 - 1)
     k = slab_nbr.shape[1]

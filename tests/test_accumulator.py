@@ -298,22 +298,75 @@ def test_topk_merge_sorted_ref_all_sentinel_rows():
         assert np.all(s_nbr[[0, 2]] == -1)
 
 
+def _merge_inputs(case, n, k, kin, rs):
+    """(slab_nbr, slab_w, inc_nbr, inc_w) of one kernel case, -1 / -inf on
+    empty slots."""
+    def rand(cols, ids):
+        return (rs.randint(-1, ids, (n, cols)).astype(np.int32),
+                rs.rand(n, cols).astype(np.float32))
+
+    def distinct(cols, ids):
+        return np.stack([rs.permutation(ids)[:cols] for _ in range(n)]
+                        ).astype(np.int32)
+
+    if case == "random":
+        (snbr, sw), (inbr, iw) = rand(k, 3 * k), rand(kin, 3 * kin)
+    elif case == "slab_in_batch":       # every batch id is also a slab id,
+        snbr = distinct(k, 10 * k)      # at another weight
+        sw = rs.rand(n, k).astype(np.float32)
+        inbr = np.take_along_axis(snbr, rs.randint(0, k, (n, kin)), 1)
+        iw = rs.rand(n, kin).astype(np.float32)
+    elif case == "batch_dups":          # a few ids, each many times
+        snbr, sw = rand(k, 10 * k)
+        inbr, iw = rand(kin, 4)
+    elif case == "tied_weights":        # ties across different neighbours
+        snbr, inbr = distinct(k, 4 * (k + kin)), rand(kin, 4 * (k + kin))[0]
+        sw = (rs.randint(1, 4, (n, k)) / 4).astype(np.float32)
+        iw = (rs.randint(1, 4, (n, kin)) / 4).astype(np.float32)
+    elif case == "empty_and_full":      # even rows empty; odd rows full,
+        both = distinct(k + kin, 4 * (k + kin))     # no id twice
+        snbr, inbr = both[:, :k], both[:, k:]
+        sw = rs.rand(n, k).astype(np.float32)
+        iw = rs.rand(n, kin).astype(np.float32)
+        snbr[::2], inbr[::2] = -1, -1
+    elif case == "signed_zeros":        # +-0 and -inf weights, id INT32_MAX
+        ids = max(8, (k + kin) // 2)    # about two instances an id
+        (snbr, _), (inbr, _) = rand(k, ids), rand(kin, ids)
+        snbr[snbr == 7], inbr[inbr == 7] = 2**31 - 1, 2**31 - 1
+        pick = np.array([0.0, -0.0, -np.inf, -0.5], np.float32)
+        sw = pick[rs.randint(0, 4, (n, k))]
+        iw = pick[rs.randint(0, 4, (n, kin))]
+    sw[snbr < 0], iw[inbr < 0] = -np.inf, -np.inf
+    return tuple(map(jnp.asarray, (snbr, sw, inbr, iw)))
+
+
+# one compile per shape, shared by the cases of that shape
+_merge_interpret = jax.jit(lambda *a: topk_merge(*a, interpret=True))
+_MERGE_CASES = [("random", 1, 4, 4), ("random", 17, 8, 8),
+                ("random", 64, 16, 8), ("random", 5, 3, 9),
+                ("random", 11, 250, 250)]
+_MERGE_CASES = [pytest.param(*c, id="-".join(map(str, c[1:])))
+                for c in _MERGE_CASES] + [
+    ("slab_in_batch", 11, 250, 250), ("batch_dups", 11, 250, 250),
+    ("tied_weights", 11, 250, 250), ("empty_and_full", 11, 250, 250),
+    ("signed_zeros", 11, 250, 250),
+    ("random", 11, 120, 250),           # k != kin, a slab under 128 lanes
+    ("slab_in_batch", 11, 300, 250),    # a row that pads to 1,024 lanes
+    ("batch_dups", 17, 8, 8), ("tied_weights", 17, 8, 8),
+    ("empty_and_full", 17, 8, 8), ("signed_zeros", 17, 8, 8)]
+
+
 @pytest.mark.fast
-@pytest.mark.parametrize("n,k,kin", [(1, 4, 4), (17, 8, 8), (64, 16, 8),
-                                     (5, 3, 9), (11, 250, 250)])
-def test_topk_merge_kernel_matches_ref(n, k, kin):
+@pytest.mark.parametrize("case,n,k,kin", _MERGE_CASES)
+def test_topk_merge_kernel_matches_ref(case, n, k, kin):
     rs = np.random.RandomState(n * k + kin)
-    def slabs(cols):
-        nbr = rs.randint(-1, 3 * cols, (n, cols)).astype(np.int32)
-        w = rs.rand(n, cols).astype(np.float32)
-        w[nbr < 0] = -np.inf
-        return jnp.asarray(nbr), jnp.asarray(w)
-    snbr, sw = slabs(k)
-    inbr, iw = slabs(kin)
-    r_nbr, r_w = ref.topk_merge_ref(snbr, sw, inbr, iw)
-    p_nbr, p_w = topk_merge(snbr, sw, inbr, iw, interpret=True)
+    args = _merge_inputs(case, n, k, kin, rs)
+    r_nbr, r_w = ref.topk_merge_ref(*args)
+    p_nbr, p_w = _merge_interpret(*args)
     np.testing.assert_array_equal(np.asarray(r_nbr), np.asarray(p_nbr))
-    np.testing.assert_array_equal(np.asarray(r_w), np.asarray(p_w))
+    # bit for bit: the sign of a zero weight too
+    np.testing.assert_array_equal(np.asarray(r_w).view(np.int32),
+                                  np.asarray(p_w).view(np.int32))
 
 
 @pytest.mark.fast

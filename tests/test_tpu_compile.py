@@ -70,10 +70,14 @@ def test_leader_score_compiles(one_chip):
              ((NW, S), jnp.bool_), ((NW, W), jnp.bool_))
 
 
-@pytest.mark.parametrize("rows", [N, N + N // 100])   # build, after extend
-def test_topk_merge_compiles(one_chip, rows):
+@pytest.mark.parametrize("rows,k", [
+    pytest.param(N, K, id=str(N)),                      # build
+    pytest.param(N + N // 100, K, id=str(N + N // 100)),  # after extend
+    pytest.param(N, 384, id="1024-lanes"),   # a row that pads to 1,024
+])
+def test_topk_merge_compiles(one_chip, rows, k):
     compiled = _compile(tm.topk_merge, one_chip,
-                        ((rows, K), jnp.int32), ((rows, K), jnp.float32),
+                        ((rows, k), jnp.int32), ((rows, k), jnp.float32),
                         ((rows, K), jnp.int32), ((rows, K), jnp.float32))
     # the merge streams rows through VMEM: nothing slab-sized is staged
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
