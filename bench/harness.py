@@ -27,7 +27,7 @@ import sys
 import tempfile
 import time
 from types import ModuleType
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -115,18 +115,54 @@ class Clock:
 
 @dataclasses.dataclass
 class Run:
-    """What a per-layer metric reader gets: the cell, the device, the
-    session's counts of the window's work, and the window's trace."""
+    """What a per-layer metric reader gets: the cell, the device kind, the
+    session's counts of the window's work, and the window's trace.  On
+    several chips the trace reads one device, the busiest in the window
+    (``bench/trace.py``): its times are one chip's, so a reader that sets
+    them against work counts that chip's share of the cell's work."""
     cell: Cell
     device_kind: str
     counts: Dict[str, int]
     trace: object
     log: Callable[[str], None] = log
 
+    def per_rep_ms(self, seconds: float) -> Optional[float]:
+        """``seconds`` of the window per repetition, in ms, or None where
+        the trace holds none."""
+        return 1e3 * seconds / self.counts["reps"] if seconds > 0 else None
+
+
+def memory_peak(devices, log: Callable[[str], None] = log) -> int:
+    """The fullest chip's ``peak_bytes_in_use`` (-1 where none reports
+    one), logging every chip's."""
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", -1))
+             for d in devices]
+    log(f"memory_peak_bytes by chip: {peaks}")
+    return max(peaks)
+
+
+def log_trace(tr) -> None:
+    """The reduction's view of the window: each device's busy time, and
+    the paced device's stage split and labelled idle gaps."""
+    busy = tr.busy_s_by_device
+    log(f"trace: busy_s={tr.busy_s} window_s={tr.window_s} "
+        f"ops={len(tr.ops)} host_spans={len(tr.spans)} paced={tr.device} "
+        f"busy_s_by_device={json.dumps(busy)}"
+        + (f" busy_max_over_min={max(busy.values()) / min(busy.values())}"
+           if busy and min(busy.values()) > 0 else ""))
+    split = tr.stages()
+    log("stages: " + " ".join(f"{k or 'unscoped'}={v:.9f}"
+                              for k, v in split.items())
+        + f" sum={sum(split.values()):.9f} busy_s={tr.busy_s:.9f}"
+        + (f" unscoped_share={100 * split[''] / tr.busy_s:.6f}%"
+           if tr.busy_s else ""))
+    log(f"idle gaps by innermost span: {tr.idle_gaps(6)}")
+
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
-             t0: float, device) -> dict:
-    """One run; returns the result line's object."""
+             t0: float, devices) -> dict:
+    """One run on ``devices``, the cell's chips; returns the result line's
+    object."""
     import jax
 
     clock = Clock()
@@ -150,7 +186,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"window_s={window_s:.4f} counts={json.dumps(session.counts())} "
         f"compiles_in_window={clock.compiles - compiles0}")
     values = dict(session.end_to_end(window_s), setup_s=setup_s)
-    peak = int((device.memory_stats() or {}).get("peak_bytes_in_use", -1))
+    peak = memory_peak(devices)
 
     result = {"correct": False, "attempted": session.attempted,
               "failed": session.failed}
@@ -158,7 +194,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         from bench import trace as trace_lib
         tr = trace_lib.Trace(trace_lib.load(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        run = Run(cell, device.device_kind, session.counts(), tr)
+        log_trace(tr)
+        run = Run(cell, devices[0].device_kind, session.counts(), tr)
         metrics = {}
         for m in cell.per_layer:
             value = cell.readers[m["name"]](run)
@@ -167,8 +204,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         dev_extra = {"busy_s": tr.busy_s, "window_s": tr.window_s}
         result["breakdown"] = {"device_ops": tr.top_ops(10),
                                "idle_gaps": tr.idle_gaps(10)}
-        log(f"trace: busy_s={tr.busy_s} window_s={tr.window_s} "
-            f"ops={len(tr.ops)} host_spans={len(tr.host)}")
     else:
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell.end_to_end}
@@ -182,9 +217,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     result["correct"] = result["attempted"] > 0 and all(
         c["value"] <= c["limit"] for c in checks.values())
     result["metrics"] = metrics
-    result["device"] = {"platform": device.platform,
-                        "kind": device.device_kind,
-                        "count": len(jax.devices()),
+    result["device"] = {"platform": devices[0].platform,
+                        "kind": devices[0].device_kind,
+                        "count": len(devices),
                         "memory_peak_bytes": peak, **dev_extra}
     result["checks"] = checks
     return result
@@ -213,7 +248,7 @@ def main(t0: float) -> int:
         f"trace={args.trace} device={devices[0].device_kind} "
         f"x{len(devices)} jax={jax.__version__}")
     result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
-                      t0=t0, device=devices[0])
+                      t0=t0, devices=devices[:cell.chips])
     for name, c in result["checks"].items():
         log(f"check {name}: {c['value']} limit {c['limit']}")
     print(json.dumps(result), flush=True)

@@ -5,5 +5,4 @@ CLASSES = ("sort",)
 
 
 def read(run):
-    t = run.trace.class_s(*CLASSES)
-    return 1e3 * t / run.counts["reps"] if t > 0 else None
+    return run.per_rep_ms(run.trace.class_s(*CLASSES))

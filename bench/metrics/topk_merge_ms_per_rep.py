@@ -5,5 +5,4 @@ NAMES = ("topk_merge",)
 
 
 def read(run):
-    t = run.trace.kernel_s(NAMES)
-    return 1e3 * t / run.counts["reps"] if t > 0 else None
+    return run.per_rep_ms(run.trace.kernel_s(NAMES))

@@ -30,15 +30,20 @@ def n_windows(n: int, window: int) -> int:
 
 
 def window_score_least_s(nw: int, s: int, w: int, d: int,
-                         peak: Dict[str, float]) -> Dict[str, float]:
-    """The least time in which a chip can score every leader against its
-    window, whatever implements it: the larger of the multiply-adds
-    (2 nw s W d FLOPs) at the bf16 peak and one read of the leader and
-    member rows (nw (W + s) d float32 bytes) at the HBM bandwidth."""
-    flops = 2.0 * nw * s * w * d
-    nbytes = 4.0 * nw * (w + s) * d
+                         peak: Dict[str, float],
+                         chips: int = 1) -> Dict[str, float]:
+    """The least time in which one chip can score its share of the leaders
+    against their windows, whatever implements it.  Of the ``nw`` window
+    rows a chip scores ``windows`` = ceil(nw / chips), every row on one
+    chip.  The time is the larger of their multiply-adds (2 windows s W d
+    FLOPs) at the bf16 peak and one read of their leader and member rows
+    (windows (W + s) d float32 bytes) at the HBM bandwidth."""
+    windows = -(-nw // chips)
+    flops = 2.0 * windows * s * w * d
+    nbytes = 4.0 * windows * (w + s) * d
     compute_s = flops / peak["bf16_flops_per_s"]
     memory_s = nbytes / peak["hbm_bytes_per_s"]
-    return {"flops": flops, "bytes": nbytes, "compute_s": compute_s,
-            "memory_s": memory_s, "least_s": max(compute_s, memory_s),
+    return {"windows": windows, "flops": flops, "bytes": nbytes,
+            "compute_s": compute_s, "memory_s": memory_s,
+            "least_s": max(compute_s, memory_s),
             "bound": "compute" if compute_s >= memory_s else "memory"}

@@ -26,7 +26,7 @@ def _cell(**over):
 
 def _run(cell, seed=2**33 + 5):
     return harness.run_cell(cell, seed, 0.2, False, t0=time.perf_counter(),
-                            device=jax.devices()[0])
+                            devices=jax.devices()[:1])
 
 
 def _state_unchanged(monkeypatch):

@@ -28,3 +28,17 @@ def test_wide_rows_stay_memory_bound():
 def test_unknown_device_kind_is_refused():
     with pytest.raises(KeyError, match="no peaks"):
         roofline.peaks("TPU v9 imaginary")
+
+
+def test_one_chip_counts_its_share_of_the_windows():
+    """On a mesh each chip scores its ceil(nw / chips) of the window rows;
+    at one chip the count, and every number, is the whole grid's."""
+    peak = roofline.peaks("TPU v5 lite")
+    nw = roofline.n_windows(1 << 20, 250)
+    whole = roofline.window_score_least_s(nw, 25, 250, 100, peak)
+    assert roofline.window_score_least_s(nw, 25, 250, 100, peak,
+                                         chips=1) == whole
+    share = roofline.window_score_least_s(nw, 25, 250, 100, peak, chips=4)
+    assert share["windows"] == -(-4196 // 4) == 1049
+    assert share == roofline.window_score_least_s(1049, 25, 250, 100, peak)
+    assert share["least_s"] < whole["least_s"] / 3.99

@@ -1,5 +1,7 @@
-"""The program's stages and spans read from a trace (``bench/stages.py``)."""
+"""The program's stages and spans read from a trace: ``bench/trace.py``,
+with the HLO lookup of ``bench/stages.py``."""
 
+import dataclasses
 import json
 import os
 
@@ -12,6 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 DATA = os.path.join(os.path.dirname(__file__), "data")
 RECORDED = os.path.join(DATA, "random1b_rep_stages.json")
 PR12 = os.path.join(DATA, "random1b_rep_trace.json")
+MESH4 = os.path.join(DATA, "random1b_mesh4_rep_stages.json")
 BUCKET = "jit(round_step)/stars.fold.bucket"
 
 # reader -> the program's names it reads (repro/scopes.py)
@@ -20,40 +23,53 @@ READERS = {"sketch_ms_per_rep": ("SKETCH",),
            "score_stage_ms_per_rep": ("SCORE",),
            "fold_ms_per_rep": ("FOLD_DEDUP", "FOLD_BUCKET"),
            "host_round_ms_per_rep": ("ROUND",)}
+DEV = "/device:TPU:0"
 
 
-@pytest.fixture(autouse=True)
-def _restore_trace_load(monkeypatch):
-    """Loading a metric reader installs the hook into ``trace.load``; each
-    test leaves ``trace.load`` and the hook's state as it found them."""
-    monkeypatch.setattr(trace, "load", trace.load)
-    monkeypatch.setattr(stages, "_last", {"scoped": None, "view": None})
+def _op(start, end, scope, name="op", device=DEV):
+    return trace.Event(device, trace.OPS_LINE, name, start, end - start,
+                       scope)
 
 
-def _op(start, end, scope, name="op"):
-    return stages.Op(start, end, name, scope)
+def _span(name, start, end):
+    return trace.Event("/host:CPU", "python", name, start, end - start)
 
 
-SCOPED = stages.Scoped(
-    ops=[_op(100, 600, f"{BUCKET}/jit(searchsorted)/while", "while.5"),
-         _op(150, 250, f"{BUCKET}/jit(searchsorted)/while/body/gather"),
-         _op(300, 400, f"{BUCKET}/jit(searchsorted)/while/body/gather"),
-         _op(40, 90, "jit(round_step)/stars.fold.dedup/sort"),
-         _op(700, 900, "jit(round_step)/stars.fold.merge/pallas_call"),
-         _op(920, 930, "jit(convert_element_type)/convert_element_type"),
-         _op(990, 1100, "jit(round_step)/stars.sketch/dot_general")],
-    spans=[stages.Span(trace.WINDOW_SPAN, 0, 1000),
-           stages.Span("bench.add_reps", 0, 100),
-           stages.Span("stars.round", 2, 38),
-           stages.Span("stars.bind", 2, 30),
-           stages.Span("bench.block", 100, 940),
-           stages.Span("bench.counters", 940, 1000),
-           stages.Span("stars.counters", 945, 985),
-           stages.Span("stars.round", 1500, 1600)])    # after the window
+EVENTS = [
+    _op(100, 600, f"{BUCKET}/jit(searchsorted)/while", "while.5"),
+    _op(150, 250, f"{BUCKET}/jit(searchsorted)/while/body/gather"),
+    _op(300, 400, f"{BUCKET}/jit(searchsorted)/while/body/gather"),
+    _op(40, 90, "jit(round_step)/stars.fold.dedup/sort"),
+    _op(700, 900, "jit(round_step)/stars.fold.merge/pallas_call"),
+    _op(920, 930, "jit(convert_element_type)/convert_element_type"),
+    _op(990, 1100, "jit(round_step)/stars.sketch/dot_general"),
+    _span(trace.WINDOW_SPAN, 0, 1000),
+    _span("bench.add_reps", 0, 100),
+    _span("stars.round", 2, 38),
+    _span("stars.bind", 2, 30),
+    _span("bench.block", 100, 940),
+    _span("bench.counters", 940, 1000),
+    _span("stars.counters", 945, 985),
+    _span("stars.round", 1500, 1600)]                # after the window
+
+
+def _recorded(path):
+    """A recorded window: ``ops`` as [start, end, instruction, scope] (on
+    TPU:0) or [device index, start, end, instruction, scope], ``spans``
+    as [name, start, end]."""
+    with open(path) as f:
+        rec = json.load(f)
+    events = []
+    for o in rec["ops"]:
+        device = f"/device:TPU:{o[0]}" if len(o) == 5 else DEV
+        start, end, instruction, scope = o[-4:]
+        events.append(_op(start, end, scope, instruction, device))
+    events += [_span(*s) for s in rec["spans"]]
+    return rec, trace.Trace(events)
 
 
 def test_a_loop_and_its_body_count_once():
-    st = stages.StageTrace(SCOPED, 0, 1000)
+    st = trace.Trace(EVENTS)
     assert st.scope_s("stars.fold.bucket") == pytest.approx(500e-9)
     assert st.scope_s("stars.fold.dedup",
                       "stars.fold.bucket") == pytest.approx(550e-9)
@@ -66,8 +82,26 @@ def test_a_loop_and_its_body_count_once():
     assert st.scope_s("stars.fold") == 0             # whole components only
 
 
+def test_stages_come_from_the_paced_device():
+    """A busier device's stages replace TPU:0's; a less busy one's are
+    not read, and add nothing to the unscoped remainder."""
+    score = "jit(round_step)/stars.score/dot_general"
+    busier = trace.Trace(EVENTS + [
+        _op(0, 600, score, device="/device:TPU:1"),
+        _op(600, 1000, "jit(round_step)/all-to-all", device="/device:TPU:1")])
+    assert busier.device == "/device:TPU:1"
+    assert busier.stages() == {"stars.score": pytest.approx(600e-9),
+                               "": pytest.approx(400e-9)}
+    assert busier.scope_s("stars.fold.bucket") == 0
+    idle = trace.Trace(EVENTS + [_op(0, 5, score, device="/device:TPU:1"),
+                                 _op(5, 9, "", device="/device:TPU:1")])
+    assert idle.device == DEV
+    assert idle.stages() == trace.Trace(EVENTS).stages()
+    assert sum(idle.stages().values()) == pytest.approx(idle.busy_s)
+
+
 def test_idle_gaps_take_the_innermost_span():
-    st = stages.StageTrace(SCOPED, 0, 1000)
+    st = trace.Trace(EVENTS)
     gaps = dict((label, s) for label, s in reversed(st.idle_gaps(10)))
     assert gaps["stars.bind"] == pytest.approx(40e-9)          # 0-40
     assert gaps["bench.block"] == pytest.approx(100e-9)        # 600-700
@@ -127,8 +161,8 @@ def test_unnamed_ops_take_a_neighbour_op_name():
 
 def test_the_trace_keeps_the_programs_hlo(tmp_path):
     """A CPU trace: the profiler stores the HLO of a program compiled
-    before the trace began, with the scopes it was traced under; the
-    harness's own reading of the trace is unchanged by the hook."""
+    before the trace began, with the scopes it was traced under, and the
+    harness's reading of the trace keeps the program's host spans."""
     import jax
     import jax.numpy as jnp
 
@@ -149,23 +183,17 @@ def test_the_trace_keeps_the_programs_hlo(tmp_path):
     ops = [op for names in programs.values() for op in names.values()]
     assert any("stars.sketch/" in op for op in ops)
 
-    plain = trace.load(str(tmp_path))
-    stages.install()
-    assert trace.load.keeps_stages
-    assert trace.load(str(tmp_path)) == plain
-    spans = stages._last["scoped"].spans
-    assert [s.name for s in spans if s.name.startswith("stars.")] \
+    tr = trace.Trace(trace.load(str(tmp_path)))
+    assert [s.name for s in tr.spans if s.name.startswith("stars.")] \
         == ["stars.round"]
 
 
 def test_pr12_readings_with_the_readers_installed():
-    """Every metric's reader is loaded, the hook with them; the recorded
-    PR 12 repetition (no scopes, no program spans) reads as it did, and
-    the new readers read nothing from it."""
+    """Every metric's reader is loaded; the first recorded repetition (no
+    scopes, no program spans) reads as it did, and the stage and span
+    readers read nothing from it."""
     cell = harness.resolve("random1b-build", ROOT)
-    assert trace.load.keeps_stages
     tr = trace.Trace(trace.load_json(PR12))
-    stages._last["scoped"] = stages.Scoped([], [])
     run = harness.Run(cell, "TPU v5 lite", {"reps": 1}, tr,
                       log=lambda msg: None)
     got = {name: read(run) for name, read in cell.readers.items()}
@@ -195,11 +223,8 @@ def test_readers_read_the_programs_names():
 def test_recorded_chip_stages():
     """One random1b-build repetition traced on a v5e: its ops with their
     scopes and its host spans, read by the new readers as on the chip."""
-    with open(RECORDED) as f:
-        rec = json.load(f)
-    scoped = stages.Scoped([stages.Op(*o) for o in rec["ops"]],
-                           [stages.Span(*s) for s in rec["spans"]])
-    st = stages.StageTrace(scoped, *rec["window"])
+    rec, st = _recorded(RECORDED)
+    assert (st.start_ns, st.end_ns) == tuple(rec["window"])
     chip = rec["read_on_chip"]
     assert 1e3 * st.scope_s("stars.sketch") == pytest.approx(
         chip["sketch_ms_per_rep"])
@@ -220,3 +245,26 @@ def test_recorded_chip_stages():
     assert not [s for s in st.spans if s.name == "stars.bind"]
     assert all(label != "host" for label, s in st.idle_gaps(50)
                if s > 1e-4)
+
+
+def test_recorded_four_chip_trace():
+    """One repetition of the mesh build (random1b at n = 2^16 on a (4,)
+    mesh of v5e chips): four devices, the paced one's stages and its
+    unscoped remainder sum to its busy time, and the scoring kernel's
+    share of its roofline, one chip's windows, stays under 100%."""
+    rec, tr = _recorded(MESH4)
+    full = rec["read_from_the_whole_trace"]
+    assert tr.devices == [f"/device:TPU:{i}" for i in range(4)]
+    assert tr.device == full["device"]
+    assert tr.busy_s_by_device == pytest.approx(full["busy_s_by_device"])
+    split = tr.stages()
+    assert split == pytest.approx(full["stages"])
+    assert abs(sum(split.values()) - tr.busy_s) <= 1e-9
+    cell = harness.resolve("random1b-build", ROOT)
+    cell = dataclasses.replace(cell, chips=4,
+                               config=dict(cell.config, n=rec["n"]))
+    run = harness.Run(cell, "TPU v5 lite", {"reps": 1}, tr,
+                      log=lambda msg: None)
+    share = cell.readers["window_score_roofline"](run)
+    assert share == pytest.approx(full["window_score_roofline"])
+    assert 0 < share <= 100

@@ -66,3 +66,21 @@ def test_run_exits_without_a_tpu():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "needs 1 TPU chip" in proc.stderr
+
+
+class _Chip:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_the_fullest_chip_sets_the_memory_peak():
+    """A cell is sized by its fullest chip; every chip's peak is logged."""
+    lines = []
+    chips = [_Chip({"peak_bytes_in_use": 7}), _Chip({"peak_bytes_in_use": 9}),
+             _Chip(None), _Chip({"peak_bytes_in_use": 8})]
+    assert harness.memory_peak(chips, lines.append) == 9
+    assert lines == ["memory_peak_bytes by chip: [7, 9, -1, 8]"]
+    assert harness.memory_peak([_Chip(None)], lines.append) == -1

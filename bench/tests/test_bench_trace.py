@@ -1,5 +1,6 @@
 """The reduction from a profiler trace to device times."""
 
+import dataclasses
 import os
 
 import pytest
@@ -89,11 +90,44 @@ def test_any_bench_span_labels_a_gap():
         ["bench.query", pytest.approx(40e-9)]]
 
 
-def test_a_trace_of_two_devices_is_refused():
-    other = trace.Event("/device:TPU:1", trace.OPS_LINE, "%sort.9 = s32[8] "
-                        "sort(s32[8] %g)", 100, 50)
-    with pytest.raises(ValueError, match="2 devices"):
-        trace.Trace(EVENTS + [other])
+def _on(device, events):
+    return [dataclasses.replace(e, plane=device)
+            if e.plane == DEV else e for e in events]
+
+
+def test_the_busiest_device_sets_the_pace():
+    """Each device's busy time is its own union; every device-time reading
+    comes from the busiest device, here TPU:1, whose gaps fall elsewhere."""
+    other = _on("/device:TPU:1", [
+        _op("%sort.1 = s32[8] sort(s32[8] %a)", 0, 450),
+        _op("%topk_merge.1 = (s32[4,2], f32[4,2]) custom-call(%c)", 500, 880),
+        _op("%window_score.1 = (f32[2,3]) custom-call(%b)", 880, 970)])
+    tr = trace.Trace(EVENTS + other)
+    assert tr.devices == [DEV, "/device:TPU:1"]
+    assert tr.busy_s_by_device == {DEV: pytest.approx(510e-9),
+                                   "/device:TPU:1": pytest.approx(920e-9)}
+    assert tr.device == "/device:TPU:1"
+    assert tr.busy_s == pytest.approx(920e-9)
+    assert tr.class_s("sort") == pytest.approx(450e-9)
+    assert tr.kernel_s(["topk_merge"]) == pytest.approx(380e-9)
+    assert tr.kernel_s(["window_score"]) == pytest.approx(90e-9)
+    assert tr.top_ops(1) == [["sort.1", pytest.approx(450e-9)]]
+    assert tr.idle_gaps(10) == [["bench.block", pytest.approx(50e-9)],
+                                ["bench.counters", pytest.approx(30e-9)]]
+
+
+def test_a_tie_goes_to_the_lowest_index():
+    """Devices list in index order (TPU:2 before TPU:10); of two equally
+    busy devices the lower index is read."""
+    events = [_host(trace.WINDOW_SPAN, 0, 100)]
+    for device, start in (("/device:TPU:10", 0), ("/device:TPU:2", 50)):
+        events += _on(device, [_op("%sort.1 = s32[8] sort(s32[8] %a)",
+                                   start, start + 40)])
+    tr = trace.Trace(events)
+    assert tr.devices == ["/device:TPU:2", "/device:TPU:10"]
+    assert tr.device == "/device:TPU:2"
+    assert tr.idle_gaps(10) == [["host", pytest.approx(50e-9)],
+                                ["host", pytest.approx(10e-9)]]
 
 
 def test_a_trace_without_the_window_span_is_refused():

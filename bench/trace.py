@@ -1,13 +1,25 @@
 """Reduction of a profiler trace of the measured window to device times.
 
-``load(path)`` flattens the JAX profiler's ``.xplane.pb`` into ``Event``s;
-``Trace`` answers what the per-layer metric readers ask: the window (the
-host span ``bench.window`` that the harness opens around it), the device's
-busy time (the union of its op intervals inside the window), the time of
-an op class or of a kernel by name, the ops that took most time, and the
-longest idle gaps labelled by the host span that was open meanwhile (the
-spans a driver opens, named ``bench.<what the host does>``).  A trace holds
-one device: every cell runs on one chip.
+``load(path)`` reads the newest ``.xplane.pb`` once into ``Event``s: every
+device op with the ``op_name`` scope the program traced it under (looked
+up in the optimized HLO that the profiler stores, ``bench/stages.py``),
+and every host span.  ``Trace`` answers what the per-layer metric readers
+ask about the window, the host span ``bench.window`` that the harness
+opens around it: the device's busy time (the union of its op intervals
+inside the window), the time of an op class, a kernel or a program stage,
+the ops that took most time, the host spans, and the longest idle gaps
+labelled by the innermost host span open meanwhile (``bench.<step>``
+around the benchmark's calls, ``stars.<step>`` inside the program's).
+
+A window of several chips reduces per device.  Each device's busy time is
+the union of its own ops' intervals, and every device-time reading comes
+from one device, the paced one: the busiest in the window, the lowest
+index on a tie.  In an SPMD round every chip waits at each collective for
+the slowest, so the busiest chip sets the build's pace; a wait inside a
+collective is an op on its device, which is how an exposed exchange
+shows.  Its idle share is the time in which even the busiest chip had
+nothing to run: what the host costs.  On one chip the paced device is the
+only one.
 """
 
 from __future__ import annotations
@@ -19,10 +31,13 @@ import os
 import re
 from typing import Callable, Dict, Iterable, List, Sequence, Tuple
 
+from bench import stages
+
 WINDOW_SPAN = "bench.window"
-HOST_SPAN_PREFIX = "bench."
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+SPAN_PREFIXES = ("bench.", "stars.")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,25 +47,41 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    scope: str = ""          # a device op's op_name, where the HLO names it
 
     @property
     def end_ns(self) -> float:
         return self.start_ns + self.dur_ns
 
 
-def load(trace_dir: str) -> List["Event"]:
-    """Every event of the newest ``.xplane.pb`` under ``trace_dir``."""
+def load(trace_dir: str) -> List[Event]:
+    """Every event of the newest ``.xplane.pb`` under ``trace_dir``, each
+    device op with the ``op_name`` of its instruction in the program whose
+    run (an event of its device's ``XLA Modules`` line) it falls in."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    with open(paths[-1], "rb") as f:
+        raw = f.read()
+    programs = stages.xspace_programs(raw)
     events = []
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        for line in plane.lines:
-            for ev in line.events:
-                events.append(Event(plane.name, line.name, ev.name,
-                                    float(ev.start_ns), float(ev.duration_ns)))
+    for plane in ProfileData.from_serialized_xspace(raw).planes:
+        lines = {line.name: list(line.events) for line in plane.lines}
+        runs = [(m.start_ns, m.start_ns + m.duration_ns,
+                 programs.get(m.name, {}))
+                for m in lines.get(MODULES_LINE, [])
+                ] if DEVICE_PLANE.match(plane.name) else []
+        for line, evs in lines.items():
+            for ev in evs:
+                start, scope = float(ev.start_ns), ""
+                if runs and line == OPS_LINE:
+                    names = next((p for s, e, p in runs if s <= start < e),
+                                 {})
+                    scope = names.get(parse_op(ev.name)[0], "")
+                events.append(Event(plane.name, line, ev.name, start,
+                                    float(ev.duration_ns), scope))
     return events
 
 
@@ -99,7 +130,7 @@ def base_name(instruction: str) -> str:
 
 
 class Trace:
-    """The device ops of one traced window."""
+    """The device ops and host spans of one traced window."""
 
     def __init__(self, events: Sequence[Event]):
         spans = [e for e in events if e.name == WINDOW_SPAN]
@@ -107,31 +138,39 @@ class Trace:
             raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
         w = max(spans, key=lambda e: e.dur_ns)
         self.start_ns, self.end_ns = w.start_ns, w.end_ns
-        def inside(e: Event) -> bool:
-            return e.end_ns > self.start_ns and e.start_ns < self.end_ns
-        self.host = [e for e in events if inside(e) and e.name != WINDOW_SPAN
-                     and e.name.startswith(HOST_SPAN_PREFIX)]
-        self.ops = [e for e in events if DEVICE_PLANE.match(e.plane)
-                    and e.line == OPS_LINE and inside(e)]
-        planes = {e.plane for e in self.ops}
-        if len(planes) > 1:
-            raise ValueError(f"ops of {len(planes)} devices in the trace; "
-                             f"the reduction reads one")
+        by_device: Dict[str, List[Event]] = {}
+        self.spans = []
+        for e in events:
+            if not (e.end_ns > self.start_ns and e.start_ns < self.end_ns):
+                continue
+            if DEVICE_PLANE.match(e.plane):
+                if e.line == OPS_LINE:
+                    by_device.setdefault(e.plane, []).append(e)
+            elif e.name.startswith(SPAN_PREFIXES) and e.name != WINDOW_SPAN:
+                self.spans.append(e)
+        self.devices = sorted(
+            by_device, key=lambda p: int(DEVICE_PLANE.match(p).group(1)))
+        self.busy_s_by_device = {
+            d: self._union_s(by_device[d]) for d in self.devices}
+        self.device = max(self.devices, key=self.busy_s_by_device.get,
+                          default=None)
+        self.ops = by_device.get(self.device, [])
 
     def _clip(self, e: Event) -> Tuple[float, float]:
         return max(e.start_ns, self.start_ns), min(e.end_ns, self.end_ns)
+
+    def _union_s(self, events: Iterable[Event]) -> float:
+        """Seconds covered by the union of ``events``' clipped intervals."""
+        return sum(e - s for s, e in union_ns(map(self._clip, events))) * 1e-9
 
     @property
     def window_s(self) -> float:
         return (self.end_ns - self.start_ns) * 1e-9
 
-    def busy_intervals(self) -> List[List[float]]:
-        return union_ns(self._clip(e) for e in self.ops)
-
     @property
     def busy_s(self) -> float:
-        """Seconds in which some op ran."""
-        return sum(e - s for s, e in self.busy_intervals()) * 1e-9
+        """Seconds in which some op ran on the paced device."""
+        return self.busy_s_by_device.get(self.device, 0.0)
 
     def time_s(self, pred: Callable[[Event], bool]) -> float:
         """Summed device time of the ops ``pred`` selects."""
@@ -153,6 +192,28 @@ class Trace:
         return self.time_s(
             lambda e: base_name(parse_op(e.name)[0]) in names)
 
+    def scope_s(self, *names: str) -> float:
+        """Seconds in which an op ran whose ``op_name`` holds one of
+        ``names`` as a component: the union of their intervals, so that a
+        loop and the ops of its body count once."""
+        return self._union_s(e for e in self.ops
+                             if not set(names).isdisjoint(e.scope.split("/")))
+
+    def stages(self) -> Dict[str, float]:
+        """{stage: scope_s(stage)} of every ``stars.`` component in the
+        window, and under ``""`` the seconds of the ops that hold none."""
+        names = {c for e in self.ops for c in e.scope.split("/")
+                 if c.startswith(stages.STAGE_PREFIX)}
+        out = {name: self.scope_s(name) for name in sorted(names)}
+        out[""] = self._union_s(e for e in self.ops
+                                if names.isdisjoint(e.scope.split("/")))
+        return out
+
+    def span_s(self, name: str) -> float:
+        """Summed seconds of the host spans called ``name``."""
+        return sum(t - s for s, t in (self._clip(e) for e in self.spans
+                                      if e.name == name)) * 1e-9
+
     def top_ops(self, k: int = 10) -> List[List]:
         """[[instruction, seconds], ...] of the ops that took most device
         time."""
@@ -166,15 +227,17 @@ class Trace:
 
     def idle_gaps(self, k: int = 10) -> List[List]:
         """[[label, seconds], ...]: the longest gaps in the device's busy
-        time inside the window, labelled by the host span open at the
-        gap's midpoint (``host`` where none is)."""
-        busy = [x for iv in self.busy_intervals() for x in iv]
+        time inside the window, labelled by the innermost (shortest) host
+        span open at the gap's midpoint (``host`` where none is)."""
+        busy = [x for iv in union_ns(map(self._clip, self.ops)) for x in iv]
         edges = [self.start_ns] + busy + [self.end_ns]
         gaps = []
         for s, e in zip(edges[0::2], edges[1::2]):
             if e > s:
                 mid = (s + e) / 2
-                label = next((h.name for h in self.host
-                              if h.start_ns <= mid < h.end_ns), "host")
+                open_ = [h for h in self.spans
+                         if h.start_ns <= mid < h.end_ns]
+                label = (min(open_, key=lambda h: h.dur_ns).name
+                         if open_ else "host")
                 gaps.append([label, (e - s) * 1e-9])
         return sorted(gaps, key=lambda g: -g[1])[:k]
