@@ -28,7 +28,8 @@ s=25, degree cap k=250) through ``GraphBuilder``, then served through
 Four chips (``--chips 4``) runs only the mesh path: a p=4 mesh build over
 ``jax.devices()`` compared slab for slab with a single-device build on
 ``devices[0]`` at the one-chip n, then one mesh build at 4x n, reporting
-recall, ``all_to_all_bytes`` and drops.
+recall, ``all_to_all_bytes`` and drops, each split by exchange (sort,
+fetch, emit).
 
 Everything runs in this one process.  Earlier lines print phase times (with
 compile time apart), counters, recall and peak device bytes; the last line
@@ -446,11 +447,15 @@ def four_chips(seed: int) -> None:
     with clock.phase("recall 4x"):
         truth = _exact_knn(x, queries, NN)
         recall = _slab_recall(nbr, queries, list(truth))
+    exchanges = ("sort", "fetch", "emit")
     _log(f"mesh 4x: n={big_n} two_hop_{NN}nn={recall:.4f} "
-         f"all_to_all_bytes={wire['all_to_all_bytes']} "
-         f"all_to_all_calls={wire['all_to_all_calls']} "
-         f"dropped={int(stats.get('dropped', 0))} "
-         f"comparisons={stats['comparisons']}")
+         f"all_to_all_bytes={wire['all_to_all_bytes']} ("
+         + " ".join(f"{e}={wire[f'all_to_all_bytes.{e}']}" for e in exchanges)
+         + f") all_to_all_calls={wire['all_to_all_calls']} "
+         f"dropped={int(stats.get('dropped', 0))} ("
+         + " ".join(f"{e}={int(stats.get(f'dropped.{e}', 0))}"
+                    for e in exchanges)
+         + f") comparisons={stats['comparisons']}")
     _check(int(stats.get("dropped", 0)) == 0, "mesh 4x drops")
     _check(stats["scored_windows"] == MESH_REPS * _n_windows(cfg, big_n),
            "mesh 4x skipped windows")

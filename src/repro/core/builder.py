@@ -958,9 +958,11 @@ class _MeshBackend:
 
     # -- slab state ----------------------------------------------------- #
     def init_state(self, capacity: int) -> acc_lib.EdgeAccumulator:
-        return jax.device_put(
-            acc_lib.EdgeAccumulator.create(self._pad_rows(self._n), capacity),
-            self._slab_sharding)
+        """Empty slabs made in place, each shard its own row block: no
+        device holds the whole (n, k) image."""
+        create = functools.partial(acc_lib.EdgeAccumulator.create,
+                                   self._pad_rows(self._n), capacity)
+        return jax.jit(create, out_shardings=self._slab_sharding)()
 
     def place_state(self, state: acc_lib.EdgeAccumulator):
         """Place an unpadded (n, k) state (e.g. a restored checkpoint):
@@ -1093,6 +1095,7 @@ class _MeshBackend:
         n = self._n
 
         @jax.jit
+        @scopes.scoped(scopes.WINDOWS)
         def offset_phase(rep):
             _, k_shift, _, _ = _rep_keys(cfg, rep)
             offset, _ = win_lib.window_layout(cfg.mode, n, cfg.window,
@@ -1195,11 +1198,14 @@ class _MeshBackend:
                     out["comparisons"], out["emitted"],
                     out["prefilter_ops"], out["scored_windows"][None])
 
-        return jax.jit(jax.shard_map(
+        # the whole program is the scoring stage, opened around the
+        # shard_map call so that what XLA makes for the body is named too
+        # (stars_dist._emit_exchange)
+        return jax.jit(scopes.scoped(scopes.SCORE)(jax.shard_map(
             score_shard, mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis, None), P(axis), P())
             + ((P(),) if has_probs else ()),
-            out_specs=tuple(P(axis) for _ in range(8))))
+            out_specs=tuple(P(axis) for _ in range(8)))))
 
     def _sort_round(self, rep):
         """sketch + distributed sort of one repetition -> slot blocks."""
@@ -1267,11 +1273,11 @@ class _MeshBackend:
         with _bind_span(key, key not in self._bound):
             _, _, fetch_table, score_fn = self._bind(
                 new_from, refresh_below, refresh_fraction)
-            rep = jnp.int32(rep_index)
+            rep = np.int32(rep_index)    # an argument, not a device program
             blk_gid, blk_bucket, drop_sort = self._sort_round(rep)
             if self._paged:
                 rows, rows_ok = self._fetch_rows_paged(blk_gid)
-                drop_fetch = jnp.zeros((1,), jnp.int32)
+                drop_fetch = 0          # the store serves rows: no exchange
             else:
                 rows, rows_ok, drop_fetch = fetch_rows_all_to_all(
                     fetch_table, blk_gid, mesh=self.mesh, axis=self.axis,
@@ -1287,10 +1293,9 @@ class _MeshBackend:
                 capacity_factor=self.EMIT_CAPACITY_FACTOR,
                 exact_weights=self.cfg.exact_weights)
             counters = {"comparisons": comparisons, "emitted": emitted,
-                        "prefilter_ops": pref_ops, "scored_windows": scored}
-            counters["dropped"] = jnp.concatenate(
-                [jnp.ravel(drop_sort), jnp.ravel(drop_fetch),
-                 jnp.ravel(drop_emit)])
+                        "prefilter_ops": pref_ops, "scored_windows": scored,
+                        "dropped.sort": drop_sort, "dropped.fetch": drop_fetch,
+                        "dropped.emit": drop_emit}
             return state, counters
 
     def run_round_pair(self, state, rep_index: int, new_from: int,
@@ -1331,7 +1336,7 @@ class _MeshBackend:
         with _bind_span(key, key not in self._bound):
             _, _, fetch_table, score_fn = self._bind(
                 new_from, refresh_below, refresh_fraction)
-            rep_a, rep_b = jnp.int32(rep_index), jnp.int32(rep_index + 1)
+            rep_a, rep_b = np.int32(rep_index), np.int32(rep_index + 1)
             gid_a, bucket_a, drop_sort_a = self._sort_round(rep_a)
             gid_b, bucket_b, drop_sort_b = self._sort_round(rep_b)
             (rows_a, rows_b), (ok_a, ok_b), drop_fetch = fetch_rows_all_to_all(
@@ -1352,13 +1357,13 @@ class _MeshBackend:
             counters_a = {"comparisons": out_a[4], "emitted": out_a[5],
                           "prefilter_ops": out_a[6],
                           "scored_windows": out_a[7],
-                          "dropped": jnp.concatenate(
-                              [jnp.ravel(drop_sort_a), jnp.ravel(drop_fetch),
-                               jnp.ravel(drop_emit)])}
+                          "dropped.sort": drop_sort_a,
+                          "dropped.fetch": drop_fetch,
+                          "dropped.emit": drop_emit}
             counters_b = {"comparisons": out_b[4], "emitted": out_b[5],
                           "prefilter_ops": out_b[6],
                           "scored_windows": out_b[7],
-                          "dropped": jnp.ravel(drop_sort_b)}
+                          "dropped.sort": drop_sort_b}
             return state, counters_a, counters_b
 
     def extend(self, new_features: PointFeatures) -> None:
@@ -1897,8 +1902,12 @@ class GraphBuilder:
             pending = jax.device_get(self._counters)
         for counters in pending:
             for key, val in counters.items():
-                totals[key] = totals.get(key, 0) + int(
-                    np.sum(np.asarray(val, np.int64)))
+                val = int(np.sum(np.asarray(val, np.int64)))
+                totals[key] = totals.get(key, 0) + val
+                if key.startswith("dropped."):
+                    # the mesh counts each exchange's drops; ``dropped`` is
+                    # their total
+                    totals["dropped"] = totals.get("dropped", 0) + val
         # session-absolute values (NOT summable across roll-ups): overwrite
         # whatever a previous roll-up or restored checkpoint left behind
         totals["reps"] = self._reps_done

@@ -35,7 +35,8 @@ module runs both primitives directly on the row-sharded slabs instead:
 
 Every exchange is the owner-keyed all_to_all pattern of
 ``distributed/stars_dist.py`` and is metered under
-``transfer_stats['all_to_all_*']`` (cross-shard slices only, 0 at p=1).
+``transfer_stats['all_to_all_*']`` and ``['all_to_all_bytes.cluster']``
+(cross-shard slices only, 0 at p=1).
 Nothing O(n * k) ever leaves the devices: ``transfer_stats['edge_fetches']``
 and ``['bytes']`` stay untouched (asserted in tests/test_cluster.py); the
 only device->host traffic is the final (n,) int32 label vector, metered
@@ -147,7 +148,7 @@ def _scatter_exchange(vec, idx, val, *, mesh, axis: str, op: str,
     """Metered wrapper: records the exchange's cross-shard wire bytes."""
     p = mesh.shape[axis]
     cap = exchange_capacity(idx.shape[0] // p, p, capacity_factor)
-    acc_lib.record_all_to_all(p * (p - 1) * cap * 2 * 4)
+    acc_lib.record_all_to_all(p * (p - 1) * cap * 2 * 4, "cluster")
     return _scatter_exchange_jit(vec, idx, val, mesh=mesh, axis=axis, op=op,
                                  capacity_factor=capacity_factor)
 
@@ -456,8 +457,8 @@ def affinity_mesh(nbr: jax.Array, w: jax.Array, *, n: int, mesh,
             break
         nl, okf = _pull(labels, nbr_flat, mesh=mesh, axis=axis,
                         capacity_factor=cf)
-        acc_lib.record_all_to_all(p * (p - 1) * cap1 * 5 * 4)
-        acc_lib.record_all_to_all(p * (p - 1) * cap2 * 3 * 4)
+        acc_lib.record_all_to_all(p * (p - 1) * cap1 * 5 * 4, "cluster")
+        acc_lib.record_all_to_all(p * (p - 1) * cap2 * 3 * 4, "cluster")
         hook_idx, hook_val, n_rec = _affinity_select(
             labels, nbr, w, nl, okf, mesh=mesh, axis=axis,
             min_similarity=min_similarity)

@@ -14,7 +14,7 @@ axis:
      near-uniform hash keys unless cap is set adversarially small),
   4. ONE all_to_all exchanges the stacked (keys..., payload) buffer
      (``jax.lax.all_to_all``; bytes recorded in
-     ``accumulator.transfer_stats['all_to_all_bytes']``),
+     ``accumulator.transfer_stats['all_to_all_bytes.sort']``),
   5. local merge-sort of the received keys (invalid slots carry all-ones
      sentinel keys and sort to the tail).
 
@@ -36,14 +36,15 @@ needs.  Two consumers build on it:
     contiguous slot block of the ~n_windows/p window rows it will score
     (``windows.shard_row_layout``), buckets riding along.  Nothing O(n)
     is replicated, and slot-space ownership delivers boundary-straddling
-    windows whole to their one owner.
+    windows whole to their one owner.  The build's sort into windows, so
+    it runs under the ``stars.windows`` stage (repro/scopes.py).
   * :func:`distributed_argsort` — the replicated *global permutation*
     (each shard scatters its payloads at their global ranks, then a psum
     replicates the result); kept for consumers that genuinely need the
     full (n,) view.
 
 Collective cost: one tiny all_gather + one O(n/p) all_to_all (recorded as
-cross-shard slices in ``transfer_stats['all_to_all_bytes']``), plus the
+cross-shard slices in ``transfer_stats['all_to_all_bytes.sort']``), plus the
 O(slots/p)-per-shard reduce-scatter (or psum) of int32 ids — the
 roofline-optimal exchange for a single-pass sort.
 """
@@ -57,6 +58,8 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.lax import all_to_all, axis_size, psum_scatter
+
+from repro import scopes
 
 SENTINEL = jnp.uint32(0xFFFFFFFF)
 
@@ -279,15 +282,15 @@ def _record_exchange(p: int, n_local: int, wire_words: int,
     the (p, cap, words) send buffer stay on their own shard and never
     cross the interconnect, so including them (as this used to, p * p)
     over-reported cross-shard traffic by p/(p-1)x (2x at p=2).
-    ``transfer_stats['all_to_all_bytes']`` is cross-shard bytes ONLY,
-    and is exactly 0 on a 1-shard mesh.  ``wire_words`` is the per-row
-    uint32 count actually shipped — bytes are accounted at WIRE width
-    (``nk`` packed key words, or ``nk + 1`` with the separate payload
-    word), not at any logical unpacked width.
+    ``transfer_stats['all_to_all_bytes.sort']`` is cross-shard bytes
+    ONLY, and is exactly 0 on a 1-shard mesh.  ``wire_words`` is the
+    per-row uint32 count actually shipped — bytes are accounted at WIRE
+    width (``nk`` packed key words, or ``nk + 1`` with the separate
+    payload word), not at any logical unpacked width.
     """
     from repro.graph.accumulator import record_all_to_all
     cap = exchange_capacity(n_local, p, capacity_factor)
-    record_all_to_all(p * (p - 1) * cap * wire_words * 4)
+    record_all_to_all(p * (p - 1) * cap * wire_words * 4, "sort")
 
 
 def distributed_sort(keys: jax.Array, payload: jax.Array,
@@ -388,8 +391,7 @@ def distributed_window_blocks(keys: jax.Array, gids: jax.Array,
     uint32 sharded over ``axis`` (shard i owns slots
     ``[i * total_slots/p, ...)``), and (p,) int32 dropped-key counts.
     """
-    words = _key_words(keys)
-    nk = len(words)
+    nk = 1 if keys.ndim == 1 else keys.shape[1]
     p = mesh.shape[axis]
     if total_slots % p:
         raise ValueError(f"total_slots {total_slots} not divisible by {p}")
@@ -400,7 +402,7 @@ def distributed_window_blocks(keys: jax.Array, gids: jax.Array,
                      nk if payload_bits is not None else nk + 1,
                      capacity_factor)
     return _window_blocks_jit(
-        jnp.asarray(slot_offset, jnp.int32), gids, *words, mesh=mesh,
+        jnp.asarray(slot_offset, jnp.int32), gids, keys, mesh=mesh,
         axis=axis, capacity_factor=capacity_factor, total_slots=total_slots,
         bucket_word=bucket_word, payload_bits=payload_bits, window=window)
 
@@ -411,20 +413,21 @@ def distributed_window_blocks(keys: jax.Array, gids: jax.Array,
                    static_argnames=("mesh", "axis", "capacity_factor",
                                     "total_slots", "bucket_word",
                                     "payload_bits", "window"))
-def _window_blocks_jit(slot_offset, gids, *words, mesh, axis,
+def _window_blocks_jit(slot_offset, gids, keys, *, mesh, axis,
                        capacity_factor, total_slots, bucket_word,
                        payload_bits, window):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.windows import PAD_BUCKET, shard_row_permutation
 
-    nk = len(words)
     p = mesh.shape[axis]
 
-    def body(offset, *args):
+    def body(offset, keys_l, gids_l):
+        # the key words split here, on each shard, not as programs of
+        # their own before the sort
         out_k, out_p, valid, dropped = _sample_sort_shard(
-            args[:nk], args[nk], axis=axis, capacity_factor=capacity_factor,
-            payload_bits=payload_bits)
+            _key_words(keys_l), gids_l, axis=axis,
+            capacity_factor=capacity_factor, payload_bits=payload_bits)
         local_count = jnp.sum(valid).astype(jnp.int32)
         counts = jax.lax.all_gather(local_count, axis)       # (p,)
         me = jax.lax.axis_index(axis)
@@ -459,11 +462,12 @@ def _window_blocks_jit(slot_offset, gids, *words, mesh, axis,
             block_bucket = jnp.where(block_gid >= 0, bsum, PAD_BUCKET)
         return block_gid, block_bucket, dropped
 
-    return shard_map(
-        body, mesh=mesh,
-        in_specs=(P(),) + tuple(P(axis) for _ in range(nk + 1)),
+    # the build's sort into windows: the stage opens around the shard_map
+    # call, so that what XLA makes for the body is named too
+    return scopes.scoped(scopes.WINDOWS)(shard_map(
+        body, mesh=mesh, in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
-    )(slot_offset, *words, gids)
+    ))(slot_offset, keys, gids)
 
 
 def distributed_argsort(keys: jax.Array, gids: jax.Array,

@@ -38,6 +38,17 @@ degree slabs row-wise over the ``data`` axis and runs, per repetition
                  O(4 bytes/point) id reduce-scatter stays unrecorded, like
                  the replicated-permutation psum it replaced).
 
+Each exchange is named twice.  On the device, as a stage of
+``repro/scopes.py``: the sample sort runs under ``stars.windows``, the
+feature fetch (bucketing, both all_to_alls, the scatter back to slot
+order) under ``stars.fetch``, and the emit (doubling, bucket-by-owner
+sort, packing, the all_to_all, the unpack) under ``stars.emit``, which
+closes before the fold's ``stars.fold.*`` stages open.  On the host, as a
+counter: ``transfer_stats['all_to_all_bytes.sort' | '.fetch' | '.emit']``
+split the wire bytes (``all_to_all_bytes`` is their sum), and the
+builder's ``stats['dropped.sort' | '.fetch' | '.emit']`` count each
+exchange's capacity drops (``stats['dropped']`` is their sum).
+
 The host never sees per-repetition edges: one slab fetch per ``finalize()``
 produces the Graph, the same single-transfer contract as the single-device
 backend.  Because phases 2-4 reproduce the single-device order, draws and
@@ -59,6 +70,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.lax import all_to_all
 
+from repro import scopes
 from repro.core.spanner import Graph
 from repro.core.stars import StarsConfig
 from repro.distributed.sorter import (exchange_capacity, pack_bit_fields,
@@ -106,6 +118,12 @@ def _emit_exchange(slab_nbr, slab_w, slab_ver, *streams,
     passing several (builder.run_round_pair); locals are concatenated
     INSIDE the shard body, so no resharding collective is inserted.
     Triples cross the wire bit-packed (``_emit_widths``).
+
+    The exchange and the fold's three steps are four shard_maps, each
+    traced under its stage (repro/scopes.py): an op that XLA makes for a
+    shard_map body, such as a constant or the expansion of a cumulative
+    sum, takes the name of the shard_map call and not of a scope opened
+    inside the body, so the stage is opened around the call.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -116,7 +134,8 @@ def _emit_exchange(slab_nbr, slab_w, slab_ver, *streams,
     nwords = -(-sum(widths) // 32)
     ns = len(streams) // 4
 
-    def emit_shard(nbr_l, w_l, ver_l, *stream_l):
+    def emit_triples(*stream_l):
+        """This shard's streams -> the triples it receives, localized."""
         src_l = jnp.concatenate([stream_l[4 * i] for i in range(ns)])
         dst_l = jnp.concatenate([stream_l[4 * i + 1] for i in range(ns)])
         w_c = jnp.concatenate([stream_l[4 * i + 2] for i in range(ns)])
@@ -172,18 +191,26 @@ def _emit_exchange(slab_nbr, slab_w, slab_ver, *streams,
                                                jnp.float32)
         # sentinel slots unpack loc all-ones >= rows (fields are unsigned)
         ok_r = node_r < rows
+        return node_r, nbr_r, w_r, ok_r, dropped
 
-        state = acc_lib._fold_triples(
-            acc_lib.EdgeAccumulator(nbr=nbr_l, w=w_l, ver=ver_l),
-            node_r, nbr_r, w_r, ok_r)
-        return state.nbr, state.w, state.ver, dropped
+    def shards(stage, fn, in_specs, out_specs):
+        return scopes.scoped(stage)(shard_map(
+            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
-    return shard_map(
-        emit_shard, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None), P(axis))
-        + tuple(P(axis) for _ in streams),
-        out_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
-    )(slab_nbr, slab_w, slab_ver, *streams)
+    row, vec = P(axis, None), P(axis)
+    state = acc_lib.EdgeAccumulator(nbr=slab_nbr, w=slab_w, ver=slab_ver)
+    state_specs = acc_lib.EdgeAccumulator(nbr=row, w=row, ver=vec)
+    *triples, dropped = shards(scopes.EMIT, emit_triples,
+                               (vec,) * len(streams), (vec,) * 5)(*streams)
+    batch = shards(scopes.FOLD_DEDUP, acc_lib._fold_dedup,
+                   (vec,) * 4, (vec,) * 4)(*triples)
+    cands = shards(scopes.FOLD_BUCKET,
+                   functools.partial(acc_lib._fold_bucket, rows,
+                                     slab_nbr.shape[1]),
+                   (vec,) * 4, (row, row, row))(*batch)
+    state = shards(scopes.FOLD_MERGE, acc_lib._fold_merge,
+                   (state_specs, row, row, row), state_specs)(state, *cands)
+    return state.nbr, state.w, state.ver, dropped
 
 
 @functools.partial(jax.jit,
@@ -245,12 +272,13 @@ def _fetch_exchange(table, *gid_parts, mesh, axis: str,
             off += sz
         return (*outs, *oks, dropped)
 
-    return shard_map(
+    # the stage opens around the shard_map call (see _emit_exchange)
+    return scopes.scoped(scopes.FETCH)(shard_map(
         fetch_shard, mesh=mesh,
         in_specs=(P(axis, None),) + tuple(P(axis) for _ in gid_parts),
         out_specs=tuple(P(axis, None) for _ in gid_parts)
         + tuple(P(axis) for _ in gid_parts) + (P(axis),),
-    )(table, *gid_parts)
+    ))(table, *gid_parts)
 
 
 def fetch_rows_all_to_all(table: jax.Array, gids: jax.Array, *, mesh,
@@ -312,8 +340,9 @@ def fetch_rows_all_to_all(table: jax.Array, gids: jax.Array, *, mesh,
                              f"mesh axis {p}")
     total = sum(g.shape[0] for g in parts)
     cap = exchange_capacity(total // p, p, capacity_factor)
-    acc_lib.record_all_to_all(p * (p - 1) * cap * 4)               # requests
-    acc_lib.record_all_to_all(p * (p - 1) * cap * table.shape[1] * 4)
+    acc_lib.record_all_to_all(p * (p - 1) * cap * 4, "fetch")     # requests
+    acc_lib.record_all_to_all(p * (p - 1) * cap * table.shape[1] * 4,
+                              "fetch")
     res = _fetch_exchange(table, *parts, mesh=mesh, axis=axis,
                           capacity_factor=capacity_factor)
     n = len(parts)
@@ -365,9 +394,11 @@ def accumulate_all_to_all(state: acc_lib.EdgeAccumulator,
     if not isinstance(src, (tuple, list)):
         src, dst, w, valid = (src,), (dst,), (w,), (valid,)
     streams, m2 = [], 0
-    for s_i, d_i, w_i, v_i in zip(src, dst, w, valid):
-        s_i, d_i = s_i.ravel(), d_i.ravel()
-        w_i, v_i = w_i.ravel(), v_i.ravel()
+    for stream in zip(src, dst, w, valid):
+        # a flat stream goes in as it is: a ravel would be a program of its
+        # own on the device, outside the emit stage
+        s_i, d_i, w_i, v_i = (a if a.ndim == 1 else a.ravel()
+                              for a in stream)
         pad = (-s_i.shape[0]) % p
         if pad:
             s_i = jnp.pad(s_i, (0, pad), constant_values=-1)
@@ -380,7 +411,8 @@ def accumulate_all_to_all(state: acc_lib.EdgeAccumulator,
     # p*(p-1) slices: the p diagonal self-buckets of the send buffer never
     # cross the interconnect (all_to_all_bytes is cross-shard-only)
     acc_lib.record_all_to_all(
-        p * (p - 1) * _emit_capacity(m2, p, capacity_factor) * nwords * 4)
+        p * (p - 1) * _emit_capacity(m2, p, capacity_factor) * nwords * 4,
+        "emit")
     nbr, ww, ver, dropped = _emit_exchange(
         state.nbr, state.w, state.ver, *streams,
         mesh=mesh, axis=axis, capacity_factor=capacity_factor,

@@ -66,6 +66,9 @@ _BIG = jnp.int32(2**31 - 1)
 # actually crosses the interconnect, so shrinking the wire format shrinks
 # the stat at identical logical traffic (benchmarks/roofline.py divides
 # it by ``comparisons`` for the bytes-per-comparison roofline rows).
+# ``all_to_all_bytes.<exchange>`` splits the same bytes by exchange (the
+# sample sort, the feature fetch, the edge emit, the cluster label
+# rounds); ``all_to_all_bytes`` is their sum.
 # ``delta_*`` meters the incremental serving path (GraphBuilder
 # ``finalize(delta=True)`` / repro.service): a delta fetch ships the (n,)
 # int32 per-row version vector plus ONLY the slab rows whose version
@@ -98,6 +101,10 @@ transfer_stats: Dict[str, int] = {"edge_fetches": 0, "bytes": 0,
                                   "checkpoint_bytes": 0,
                                   "all_to_all_calls": 0,
                                   "all_to_all_bytes": 0,
+                                  "all_to_all_bytes.sort": 0,
+                                  "all_to_all_bytes.fetch": 0,
+                                  "all_to_all_bytes.emit": 0,
+                                  "all_to_all_bytes.cluster": 0,
                                   "delta_fetches": 0,
                                   "delta_bytes": 0,
                                   "delta_rows": 0,
@@ -117,12 +124,14 @@ def reset_transfer_stats() -> None:
         transfer_stats[k] = 0
 
 
-def record_all_to_all(nbytes: int) -> None:
-    """Account one explicit all_to_all exchange (CROSS-SHARD buffer bytes
-    moved, i.e. the p*(p-1) off-diagonal slices; computed host-side from
-    static shapes — callers exclude their diagonal self-buckets)."""
+def record_all_to_all(nbytes: int, exchange: str) -> None:
+    """Account one explicit all_to_all of ``exchange`` (``sort``,
+    ``fetch``, ``emit`` or ``cluster``): CROSS-SHARD buffer bytes moved,
+    i.e. the p*(p-1) off-diagonal slices, computed host-side from static
+    shapes — callers exclude their diagonal self-buckets."""
     transfer_stats["all_to_all_calls"] += 1
     transfer_stats["all_to_all_bytes"] += int(nbytes)
+    transfer_stats[f"all_to_all_bytes.{exchange}"] += int(nbytes)
 
 
 @jax.tree_util.register_dataclass
@@ -284,86 +293,110 @@ def _fold_triples(state: EdgeAccumulator, node: jax.Array, nbr: jax.Array,
     block, exactly like the slab data itself).
     """
     with jax.named_scope(scopes.FOLD_DEDUP):
-        n, cap = state.nbr.shape
-        node = node.astype(jnp.int32)
-        nbr = nbr.astype(jnp.int32)
-        ww = ww.astype(jnp.float32)
-        # NB: no node != nbr check here — self-loop exclusion happens on
-        # GLOBAL ids in the caller (``node`` may be in shard-row
-        # coordinates).
-        ok2 = ok2 & (node >= 0) & (nbr >= 0)
-        m2 = node.shape[0]
-        kin = min(cap, m2)
-
-        node_k = jnp.where(ok2, node, _BIG)
-        nbr_k = jnp.where(ok2, nbr, _BIG)
-        negw = jnp.where(ok2, -ww, jnp.inf)
-
-        # 1) dedup within the batch: group by (node, nbr), heaviest instance
-        #    first; later instances of a group are dropped.
-        node_s, nbr_s, negw_s = jax.lax.sort((node_k, nbr_k, negw), num_keys=3)
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool),
-             (node_s[1:] != node_s[:-1]) | (nbr_s[1:] != nbr_s[:-1])])
-        keep = first & (node_s != _BIG)
-
+        batch = _fold_dedup(node, nbr, ww, ok2)
     with jax.named_scope(scopes.FOLD_BUCKET):
-        # 2) bucket: per-node rank by weight, scatter the top kin of each node
-        #    into fixed (n, kin) candidate rows.  Candidates beyond rank kin
-        #    (>= cap) can never enter the final top-cap, so dropping them here
-        #    is exact.
-        node_k2 = jnp.where(keep, node_s, _BIG)
-        negw2 = jnp.where(keep, negw_s, jnp.inf)
-        nbr_k2 = jnp.where(keep, nbr_s, _BIG)
-        iota1 = jnp.arange(m2, dtype=jnp.int32)
-        node_f, negw_f, nbr_f, p1 = jax.lax.sort(
-            (node_k2, negw2, nbr_k2, iota1), num_keys=3)
-        starts = jnp.searchsorted(node_f, jnp.arange(n, dtype=jnp.int32))
-        live = node_f != _BIG
-        node_c = jnp.where(live, node_f, 0)
-        rank = (jnp.arange(m2, dtype=jnp.int32)
-                - starts[node_c].astype(jnp.int32))
-        slot = jnp.where(live & (rank < kin), rank, kin)     # kin -> dropped
-        inc_nbr = jnp.full((n, kin), -1, jnp.int32).at[node_c, slot].set(
-            nbr_f, mode="drop")
-        inc_w = jnp.full((n, kin), -jnp.inf, jnp.float32).at[node_c, slot].set(
-            -negw_f, mode="drop")
-
-        # 2b) CPU only: nbr-ascending companion view of the same survivors, so
-        #     the merge-path slab merge needs no sort at all (the step-1 order
-        #     is already (node, nbr); a few stream-length scatters re-express
-        #     it per node row).  TPU skips this — the Pallas kernel dedups in
-        #     VMEM and never reads the companion view.
-        presorted = None
-        if not kernel_ops.pallas_by_default():
-            # weight-order slot of every step-1 element (kin == dropped/dead)
-            wrank1 = jnp.zeros((m2,), jnp.int32).at[p1].set(slot)
-            surv1 = (wrank1 < kin).astype(jnp.int32)
-            excl = jnp.cumsum(surv1) - surv1             # survivors before e
-            starts1 = jnp.searchsorted(node_s, jnp.arange(n, dtype=jnp.int32))
-            node1 = jnp.where(node_s != _BIG, node_s, 0)
-            nbr_rank = excl - excl[starts1[node1]]       # rank among node's
-            slot_bn = jnp.where(surv1 == 1, nbr_rank, kin)  # survivors, by nbr
-            nbr_bn = jnp.full((n, kin), _BIG, jnp.int32).at[
-                node1, slot_bn].set(nbr_s, mode="drop")
-            negw_bn = jnp.full((n, kin), jnp.inf, jnp.float32).at[
-                node1, slot_bn].set(negw_s, mode="drop")
-            idx_bn = jnp.full((n, kin), kin, jnp.int32).at[node1, slot_bn].set(
-                wrank1, mode="drop")
-            presorted = (nbr_bn, negw_bn, idx_bn)
-
+        rows = _fold_bucket(*state.nbr.shape, *batch)
     with jax.named_scope(scopes.FOLD_MERGE):
-        # 3) merge into the running slabs (Pallas on TPU; sort-free merge-path
-        #    jnp ref on CPU — both sides are weight-sorted and deduped by
-        #    construction)
-        new_nbr, new_w = kernel_ops.topk_merge(
-            state.nbr, state.w, inc_nbr, inc_w, sorted_inputs=True,
-            inc_presorted=presorted)
-        # exact per-row change detection (empty slots compare equal: -1 == -1,
-        # and -inf == -inf is True in IEEE) -> bump changed rows' versions
-        changed = jnp.any((new_nbr != state.nbr) | (new_w != state.w), axis=1)
-        return EdgeAccumulator(nbr=new_nbr, w=new_w,
-                               ver=state.ver + changed.astype(jnp.int32))
+        return _fold_merge(state, *rows)
+
+
+# The fold's three stages as functions of their own, so that the mesh emit
+# (distributed/stars_dist.py) can run each as a shard_map under its stage.
+
+
+def _fold_dedup(node: jax.Array, nbr: jax.Array, ww: jax.Array,
+                ok2: jax.Array):
+    """Step 1: the stream sorted by (node, nbr, weight desc), and which
+    elements are the first of their (node, nbr) group."""
+    node = node.astype(jnp.int32)
+    nbr = nbr.astype(jnp.int32)
+    ww = ww.astype(jnp.float32)
+    # NB: no node != nbr check here — self-loop exclusion happens on
+    # GLOBAL ids in the caller (``node`` may be in shard-row
+    # coordinates).
+    ok2 = ok2 & (node >= 0) & (nbr >= 0)
+
+    node_k = jnp.where(ok2, node, _BIG)
+    nbr_k = jnp.where(ok2, nbr, _BIG)
+    negw = jnp.where(ok2, -ww, jnp.inf)
+
+    # 1) dedup within the batch: group by (node, nbr), heaviest instance
+    #    first; later instances of a group are dropped.
+    node_s, nbr_s, negw_s = jax.lax.sort((node_k, nbr_k, negw), num_keys=3)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool),
+         (node_s[1:] != node_s[:-1]) | (nbr_s[1:] != nbr_s[:-1])])
+    keep = first & (node_s != _BIG)
+    return node_s, nbr_s, negw_s, keep
+
+
+def _fold_bucket(n: int, cap: int, node_s: jax.Array, nbr_s: jax.Array,
+                 negw_s: jax.Array, keep: jax.Array):
+    """Steps 2 and 2b: the kept elements ranked per node into (n, kin)
+    candidate rows for slabs of ``n`` rows and ``cap`` columns."""
+    m2 = node_s.shape[0]
+    kin = min(cap, m2)
+    # 2) bucket: per-node rank by weight, scatter the top kin of each node
+    #    into fixed (n, kin) candidate rows.  Candidates beyond rank kin
+    #    (>= cap) can never enter the final top-cap, so dropping them here
+    #    is exact.
+    node_k2 = jnp.where(keep, node_s, _BIG)
+    negw2 = jnp.where(keep, negw_s, jnp.inf)
+    nbr_k2 = jnp.where(keep, nbr_s, _BIG)
+    iota1 = jnp.arange(m2, dtype=jnp.int32)
+    node_f, negw_f, nbr_f, p1 = jax.lax.sort(
+        (node_k2, negw2, nbr_k2, iota1), num_keys=3)
+    starts = jnp.searchsorted(node_f, jnp.arange(n, dtype=jnp.int32))
+    live = node_f != _BIG
+    node_c = jnp.where(live, node_f, 0)
+    rank = (jnp.arange(m2, dtype=jnp.int32)
+            - starts[node_c].astype(jnp.int32))
+    slot = jnp.where(live & (rank < kin), rank, kin)     # kin -> dropped
+    inc_nbr = jnp.full((n, kin), -1, jnp.int32).at[node_c, slot].set(
+        nbr_f, mode="drop")
+    inc_w = jnp.full((n, kin), -jnp.inf, jnp.float32).at[node_c, slot].set(
+        -negw_f, mode="drop")
+
+    # 2b) CPU only: nbr-ascending companion view of the same survivors, so
+    #     the merge-path slab merge needs no sort at all (the step-1 order
+    #     is already (node, nbr); a few stream-length scatters re-express
+    #     it per node row).  TPU skips this — the Pallas kernel dedups in
+    #     VMEM and never reads the companion view.
+    presorted = None
+    if not kernel_ops.pallas_by_default():
+        # weight-order slot of every step-1 element (kin == dropped/dead)
+        wrank1 = jnp.zeros((m2,), jnp.int32).at[p1].set(slot)
+        surv1 = (wrank1 < kin).astype(jnp.int32)
+        excl = jnp.cumsum(surv1) - surv1             # survivors before e
+        starts1 = jnp.searchsorted(node_s, jnp.arange(n, dtype=jnp.int32))
+        node1 = jnp.where(node_s != _BIG, node_s, 0)
+        nbr_rank = excl - excl[starts1[node1]]       # rank among node's
+        slot_bn = jnp.where(surv1 == 1, nbr_rank, kin)  # survivors, by nbr
+        nbr_bn = jnp.full((n, kin), _BIG, jnp.int32).at[
+            node1, slot_bn].set(nbr_s, mode="drop")
+        negw_bn = jnp.full((n, kin), jnp.inf, jnp.float32).at[
+            node1, slot_bn].set(negw_s, mode="drop")
+        idx_bn = jnp.full((n, kin), kin, jnp.int32).at[node1, slot_bn].set(
+            wrank1, mode="drop")
+        presorted = (nbr_bn, negw_bn, idx_bn)
+    return inc_nbr, inc_w, presorted
+
+
+def _fold_merge(state: EdgeAccumulator, inc_nbr: jax.Array,
+                inc_w: jax.Array, presorted) -> EdgeAccumulator:
+    """Step 3: the candidate rows merged into the slabs, and the row-version
+    bump of every row that changed."""
+    # 3) merge into the running slabs (Pallas on TPU; sort-free merge-path
+    #    jnp ref on CPU — both sides are weight-sorted and deduped by
+    #    construction)
+    new_nbr, new_w = kernel_ops.topk_merge(
+        state.nbr, state.w, inc_nbr, inc_w, sorted_inputs=True,
+        inc_presorted=presorted)
+    # exact per-row change detection (empty slots compare equal: -1 == -1,
+    # and -inf == -inf is True in IEEE) -> bump changed rows' versions
+    changed = jnp.any((new_nbr != state.nbr) | (new_w != state.w), axis=1)
+    return EdgeAccumulator(nbr=new_nbr, w=new_w,
+                           ver=state.ver + changed.astype(jnp.int32))
 
 
 def to_graph(state: EdgeAccumulator, *,
